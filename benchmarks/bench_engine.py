@@ -39,6 +39,7 @@ import sys
 import time
 
 from repro.core import SimConfig, make_trace, run_strategy
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.trace import (GAGE_PROFILE, OOI_PROFILE,
                               StreamingRequestSource,
                               StreamingTraceSynthesizer, TraceGenerator,
@@ -330,6 +331,7 @@ def run_full_trace(n_requests: int, engines: list[str],
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small traces, single rep (CI regression check)")

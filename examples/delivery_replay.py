@@ -10,10 +10,12 @@ import time
 sys.path.insert(0, "src")
 
 from repro.core import SimConfig, make_trace, run_strategy
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.trace import GAGE_PROFILE, OOI_PROFILE
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", default="ooi", choices=["ooi", "gage"])
     ap.add_argument("--scale", type=float, default=0.06)

@@ -108,7 +108,8 @@ class SimConfig:
     # the sweep when an audit check is order-sensitive).  Sharding pays
     # off on balanced traces / many-core hosts; OOI-like skew (~68% of
     # requests on one DTN) caps its parallel gain.  Other engines ignore
-    # this knob.
+    # this knob.  The workers are forked: never set ``N > 1`` in a process
+    # that holds a TPU (e.g. after any hpm run there).
     interval_shards: int | None = None
     # Interval engine only, execution knob (never changes results): back
     # the fused block replay's caches with the flat array-backed

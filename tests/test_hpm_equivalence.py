@@ -45,8 +45,9 @@ _MODEL = ARIMA(n=16, steps=60)
 # ---------------------------------------------------------------------------
 
 if HAS_HYPOTHESIS:
-    finite = st.floats(min_value=1e-3, max_value=1e6, allow_nan=False,
-                       allow_infinity=False, width=32)
+    # width=32 bounds must be exact float32 values (1e-3 is not)
+    finite = st.floats(min_value=float(np.float32(1e-3)), max_value=1e6,
+                       allow_nan=False, allow_infinity=False, width=32)
 
     @settings(max_examples=15, deadline=None)
     @given(st.lists(st.lists(finite, min_size=0, max_size=24), min_size=1,
