@@ -21,7 +21,8 @@ Each phase prints one JSON line:
    identical integer counters.
 
 Compile counts and seconds come from JAX's own monitoring events; bank
-calls are counted by wrapping the bank programs in this script.  The last
+calls are the program's own counter (``repro.core.telemetry``), which
+counts the dispatches of ``ARIMA.batched_forecast``.  The last
 line, ``{"ok": true, "device": {...}}``, is printed only when every phase
 passed.
 """
@@ -42,7 +43,7 @@ import numpy as np  # noqa: E402
 import bench_engine  # noqa: E402
 from repro.core import (SimConfig, StreamingRequestSource,  # noqa: E402
                         make_trace, run_strategy)
-from repro.core import arima  # noqa: E402
+from repro.core import arima, telemetry  # noqa: E402
 from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.trace import GAGE_PROFILE, OOI_PROFILE  # noqa: E402
 
@@ -108,24 +109,8 @@ class CompileStats:
                 "cache_hits": hits}
 
 
-class BankCalls:
-    """Counts dispatches of the ARIMA bank programs by wrapping what
-    ``arima._compiled_bank`` returns (the library itself is unchanged)."""
-
-    def __init__(self):
-        self.n = 0
-        compiled_bank = arima._compiled_bank
-
-        def counted(*key):
-            program = compiled_bank(*key)
-
-            def call(rows):
-                self.n += 1
-                return program(rows)
-
-            return call
-
-        arima._compiled_bank = counted
+def bank_calls() -> int:
+    return telemetry.counters().get("bank_calls", 0)
 
 
 def require_tpu() -> jax.Device:
@@ -136,10 +121,9 @@ def require_tpu() -> jax.Device:
     return dev
 
 
-def device_phase(dev: jax.Device, stats: CompileStats, bank_calls: BankCalls,
-                 cache_dir: str) -> None:
+def device_phase(dev: jax.Device, stats: CompileStats, cache_dir: str) -> None:
     """Phase 1: one full bank call per history bucket, on the chip."""
-    snap, calls = stats.snapshot(), bank_calls.n
+    snap, calls = stats.snapshot(), bank_calls()
     model = arima.ARIMA()
     cpu = jax.devices("cpu")[0]
     rng = np.random.default_rng(SEED)
@@ -163,17 +147,17 @@ def device_phase(dev: jax.Device, stats: CompileStats, bank_calls: BankCalls,
     emit(phase="device", platform=dev.platform, kind=dev.device_kind,
          count=len(jax.devices()), compile_cache=cache_dir,
          bank_call_s=bank_s, bank_vs_cpu_rel_diff_max_median=rel_diff,
-         **stats.since(snap), bank_calls=bank_calls.n - calls)
+         **stats.since(snap), bank_calls=bank_calls() - calls)
 
 
-def hpm_run(requests, profile, cfg, train, engine, stats, bank_calls):
-    snap, calls = stats.snapshot(), bank_calls.n
+def hpm_run(requests, profile, cfg, train, engine, stats):
+    snap, calls = stats.snapshot(), bank_calls()
     t0 = time.perf_counter()
     res = run_strategy("hpm", requests, profile.grid, cfg, train,
                        engine=engine)
     wall = time.perf_counter() - t0
     row = {"wall_s": wall, **stats.since(snap),
-           "bank_calls": bank_calls.n - calls, "recall": res.recall,
+           "bank_calls": bank_calls() - calls, "recall": res.recall,
            "origin_requests": res.origin_requests}
     return res, row
 
@@ -185,8 +169,7 @@ def hpm_config(profile, test) -> SimConfig:
     ).calibrate_origin(test)
 
 
-def hpm_phase(stats: CompileStats, bank_calls: BankCalls,
-              scale: float = HPM_SCALE) -> tuple:
+def hpm_phase(stats: CompileStats, scale: float = HPM_SCALE) -> tuple:
     """Phase 2: paper-scale hpm on OOI and GAGE.  Returns the OOI split and
     its integer counters for phase 3."""
     for name, profile in HPM_TRACES.items():
@@ -196,7 +179,7 @@ def hpm_phase(stats: CompileStats, bank_calls: BankCalls,
         train, test = tr[:split], tr[split:]
         trace_s = time.perf_counter() - t0
         res, row = hpm_run(test, profile, hpm_config(profile, test), train,
-                           "vector", stats, bank_calls)
+                           "vector", stats)
         counters = bench_engine._counters(res)
         emit(phase="hpm", trace=name, scale=scale, requests=len(test),
              trace_s=trace_s, **row, counters=counters, cpu=CPU_HPM[name])
@@ -205,13 +188,12 @@ def hpm_phase(stats: CompileStats, bank_calls: BankCalls,
     return ooi
 
 
-def streamed_phase(stats: CompileStats, bank_calls: BankCalls, train, test,
-                   counters) -> None:
+def streamed_phase(stats: CompileStats, train, test, counters) -> None:
     """Phase 3: the OOI split streamed in windows == materialized."""
     profile = HPM_TRACES["ooi"]
     source = StreamingRequestSource.from_requests(test, window=STREAM_WINDOW)
     res, row = hpm_run(source, profile, hpm_config(profile, test), train,
-                       "vector", stats, bank_calls)
+                       "vector", stats)
     streamed = bench_engine._counters(res)
     emit(phase="streamed", trace="ooi", window=STREAM_WINDOW,
          requests=res.total_requests, **row,
@@ -220,8 +202,7 @@ def streamed_phase(stats: CompileStats, bank_calls: BankCalls, train, test,
           f"streamed counters {streamed} != materialized {counters}")
 
 
-def online_phase(stats: CompileStats, bank_calls: BankCalls,
-                 scale: float = ONLINE_SCALE) -> None:
+def online_phase(stats: CompileStats, scale: float = ONLINE_SCALE) -> None:
     """Phase 4: batched (vector) == online (reference) hpm counters."""
     profile = bench_engine.PROFILES[ONLINE_PROFILE]
     train, test = bench_engine.get_split(ONLINE_PROFILE, scale)
@@ -231,8 +212,7 @@ def online_phase(stats: CompileStats, bank_calls: BankCalls,
             stream_rate_bytes_per_s=profile.bytes_per_second_stream,
             cache_bytes=128 << 30, chunk_seconds=3600.0,
         ).calibrate_origin(test)
-        res, rows[engine] = hpm_run(test, profile, cfg, train, engine, stats,
-                                    bank_calls)
+        res, rows[engine] = hpm_run(test, profile, cfg, train, engine, stats)
         counters[engine] = bench_engine._counters(res)
     match = counters["vector"] == counters["reference"]
     emit(phase="online", trace=ONLINE_PROFILE, scale=scale,
@@ -244,11 +224,10 @@ def online_phase(stats: CompileStats, bank_calls: BankCalls,
 def main() -> None:
     cache_dir = enable_compile_cache()
     stats = CompileStats()
-    bank_calls = BankCalls()
     dev = require_tpu()
-    device_phase(dev, stats, bank_calls, cache_dir)
-    streamed_phase(stats, bank_calls, *hpm_phase(stats, bank_calls))
-    online_phase(stats, bank_calls)
+    device_phase(dev, stats, cache_dir)
+    streamed_phase(stats, *hpm_phase(stats))
+    online_phase(stats)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -52,6 +52,7 @@ from repro.core.delivery import (PeerFetchRange, coalesce_peer_fetches,
                                  coalesce_peer_ranges,
                                  select_peer_sources,
                                  select_peer_sources_ranges)
+from repro.core import telemetry
 from repro.core.hpm import PrefetchOp
 from repro.core.placement import PlacementEngine
 from repro.core.simulator import (DEFAULT_BANDWIDTH_GBPS, GBPS,
@@ -88,6 +89,11 @@ class _LazyOutcomes(collections.abc.Sequence):
 
     def __iter__(self):
         return iter(self._materialize())
+
+
+def _pending_pushes(heap: list) -> int:
+    """Stream pushes waiting in a dynamic event heap."""
+    return sum(1 for e in heap if e[2] == "s")
 
 
 def origin_submit(free_at: list, overhead: float, now: float,
@@ -259,9 +265,10 @@ class VectorVDCSimulator:
     def run(self, requests: Sequence[Request], name: str = "") -> SimResult:
         if isinstance(requests, StreamingRequestSource):
             return self._run_stream(requests, name)
-        arr = requests_to_arrays(requests)
+        with telemetry.span("vdc.engine.prep"):
+            arr = requests_to_arrays(requests)
+            A = self._prep_window(arr)
         n_req = len(arr)
-        A = self._prep_window(arr)
         stream_engine = getattr(self.pf, "streaming", None)
         static = (self.placement is None and stream_engine is None
                   and getattr(self.pf, "static", False))
@@ -393,18 +400,22 @@ class VectorVDCSimulator:
             if planner_fn is not None:
                 planner = planner_fn()
         first = True
-        for window in source.windows():
-            arr = requests_to_arrays(window)
-            A = self._prep_window(arr, hint=hint, grow=not first)
-            first = False
-            if static:
-                self._run_static(A)
-            else:
-                self._run_dyn_window(A, stream_engine, heap, counter, planner)
-            agg.add_columns(self._o_bytes, self._o_lat, self._o_tra,
-                            self._o_loc, self._o_pref, self._o_peer,
-                            self._o_org, self._o_pt)
-            origin_requests += int((self._o_org > 0).sum())
+        for i, window in enumerate(source.windows()):
+            with telemetry.window(i):
+                with telemetry.span("vdc.engine.prep"):
+                    arr = requests_to_arrays(window)
+                    A = self._prep_window(arr, hint=hint, grow=not first)
+                first = False
+                if static:
+                    self._run_static(A)
+                else:
+                    self._run_dyn_window(A, stream_engine, heap, counter,
+                                         planner)
+                with telemetry.span("vdc.engine.fold"):
+                    agg.add_columns(self._o_bytes, self._o_lat, self._o_tra,
+                                    self._o_loc, self._o_pref, self._o_peer,
+                                    self._o_org, self._o_pt)
+                    origin_requests += int((self._o_org > 0).sum())
             n_total += len(arr)
         if first:
             # empty source: allocate the (empty) address space so cache
@@ -875,7 +886,8 @@ class VectorVDCSimulator:
         reqs = None
         plan_fn = getattr(self.pf, "plan", None)
         if plan_fn is not None and self.cfg.batched_prediction:
-            reqs = self._scaled_requests(A)
+            with telemetry.span("vdc.engine.prep"):
+                reqs = self._scaled_requests(A)
             plan = plan_fn(reqs)
         heap: list = []
         counter = itertools.count(len(A["arr"]))   # requests own 0..n-1
@@ -889,7 +901,8 @@ class VectorVDCSimulator:
         shared merged loop against the persistent event heap."""
         plan = reqs = None
         if planner is not None:
-            reqs = self._scaled_requests(A)
+            with telemetry.span("vdc.engine.prep"):
+                reqs = self._scaled_requests(A)
             plan = planner.plan_window(reqs)
         self._dyn_loop(A, stream_engine, heap, counter, plan, reqs)
 
@@ -901,16 +914,26 @@ class VectorVDCSimulator:
                         arr.continent.tolist()))
 
     def _dyn_drain(self, heap: list, stream_engine) -> None:
-        while heap:
-            t, _, kind, payload = heapq.heappop(heap)
-            if kind == "s":
-                if stream_engine is not None:
-                    self._apply_push(payload)
-            else:
-                self._apply_prefetch(payload, t)
+        with telemetry.span("vdc.engine.drain") as sp:
+            apply_prefetch = sp.timed("prefetch_ns", self._apply_prefetch)
+            apply_push = sp.timed("push_ns", self._apply_push)
+            n_push = _pending_pushes(heap)
+            sp.count(prefetch_events=len(heap) - n_push, push_events=n_push)
+            while heap:
+                t, _, kind, payload = heapq.heappop(heap)
+                if kind == "s":
+                    if stream_engine is not None:
+                        apply_push(payload)
+                else:
+                    apply_prefetch(payload, t)
 
     def _dyn_loop(self, A: dict, stream_engine, heap: list, counter,
                   plan, reqs) -> None:
+        """The merged event loop over one window's requests and the dynamic
+        event heap.  Event counts come from the heap's size and the stream
+        engine's totals at entry and exit, so the loop counts nothing per
+        event; with a trace running, the per-event calls are timed
+        wrappers chosen here once."""
         arr = A["arr"]
         n_req = len(arr)
         cfg = self.cfg
@@ -925,43 +948,70 @@ class VectorVDCSimulator:
         pf = self.pf
         placement = self.placement
         user_dtn = self._user_dtn
-        i = 0
-        while i < n_req:
-            if heap and heap[0][0] < now_l[i]:
-                t, _, kind, payload = heapq.heappop(heap)
-                if kind == "s":
-                    if stream_engine is not None:
-                        self._apply_push(payload)
-                else:
-                    self._apply_prefetch(payload, t)
-                continue
-            idx = i
-            i += 1
-            now = now_l[idx]
-            dtn = dtn_l[idx]
-            r_scaled = (reqs[idx] if reqs is not None else
-                        Request(now, user_l[idx], obj_l[idx], trs_l[idx],
-                                tre_l[idx], size_l[idx], cont_l[idx]))
-            user_dtn[r_scaled.user_id] = dtn
-            self._recent_requests.append(r_scaled)
-            absorbed = bool(stream_engine and stream_engine.absorb(r_scaled))
-            self._serve_event(idx, now, dtn, absorbed, True)
-            if plan is None:
-                ops = pf.observe(r_scaled)
-            else:
-                ops = plan.ops[idx]
-                for sub in plan.subscriptions[idx]:
-                    stream_engine.subscribe(*sub)
-            for op in ops:
-                heapq.heappush(heap, (max(now, op.issue_ts), next(counter),
-                                      "p", op))
+        heap_in, push_in = len(heap), _pending_pushes(heap)
+        emitted_in = absorbed_in = 0
+        absorb = subscribe = pushes_until = None
+        n_ops = 0 if plan is None else sum(map(len, plan.ops))
+        with telemetry.span("vdc.engine.loop") as sp:
+            serve = sp.timed("serve_ns", self._serve_event)
+            apply_prefetch = sp.timed("prefetch_ns", self._apply_prefetch)
+            apply_push = sp.timed("push_ns", self._apply_push)
             if stream_engine is not None:
-                for push in stream_engine.pushes_until(now):
-                    heapq.heappush(heap, (push.ts, next(counter), "s", push))
-            if (placement is not None
-                    and now - self._last_placement_ts >= cfg.placement_period):
-                self._run_placement(now)
-                self._last_placement_ts = now
+                emitted_in = stream_engine.pushes_emitted
+                absorbed_in = stream_engine.requests_absorbed
+                absorb = sp.timed("stream_ns", stream_engine.absorb)
+                subscribe = sp.timed("stream_ns", stream_engine.subscribe)
+                pushes_until = sp.timed("stream_ns",
+                                        stream_engine.pushes_until)
+            i = 0
+            while i < n_req:
+                if heap and heap[0][0] < now_l[i]:
+                    t, _, kind, payload = heapq.heappop(heap)
+                    if kind == "s":
+                        if stream_engine is not None:
+                            apply_push(payload)
+                    else:
+                        apply_prefetch(payload, t)
+                    continue
+                idx = i
+                i += 1
+                now = now_l[idx]
+                dtn = dtn_l[idx]
+                r_scaled = (reqs[idx] if reqs is not None else
+                            Request(now, user_l[idx], obj_l[idx], trs_l[idx],
+                                    tre_l[idx], size_l[idx], cont_l[idx]))
+                user_dtn[r_scaled.user_id] = dtn
+                self._recent_requests.append(r_scaled)
+                absorbed = bool(stream_engine and absorb(r_scaled))
+                serve(idx, now, dtn, absorbed, True)
+                if plan is None:
+                    ops = pf.observe(r_scaled)
+                    n_ops += len(ops)
+                else:
+                    ops = plan.ops[idx]
+                    for sub in plan.subscriptions[idx]:
+                        subscribe(*sub)
+                for op in ops:
+                    heapq.heappush(heap, (max(now, op.issue_ts),
+                                          next(counter), "p", op))
+                if stream_engine is not None:
+                    for push in pushes_until(now):
+                        heapq.heappush(heap, (push.ts, next(counter), "s",
+                                              push))
+                if (placement is not None
+                        and now - self._last_placement_ts
+                        >= cfg.placement_period):
+                    with telemetry.span("vdc.engine.placement"):
+                        self._run_placement(now)
+                    self._last_placement_ts = now
+            n_pushes = n_absorbed = 0
+            if stream_engine is not None:
+                n_pushes = stream_engine.pushes_emitted - emitted_in
+                n_absorbed = stream_engine.requests_absorbed - absorbed_in
+            applied = heap_in + n_ops + n_pushes - len(heap)
+            pushes = push_in + n_pushes - _pending_pushes(heap)
+            sp.count(requests=n_req, prefetch_events=applied - pushes,
+                     push_events=pushes, absorbed=n_absorbed)
 
     # -- serving -------------------------------------------------------------
 

@@ -40,6 +40,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.arima import (ARIMA, _gap_stats, clamp_forecast_gap,
                               predict_next_timestamp)
 from repro.core.classify import REALTIME_PERIOD
@@ -280,93 +281,96 @@ class BatchedHPMPlanner:
                     ) -> list[Sequence[PrefetchOp]]:
         """Plan one timestamp-ordered window of the trace, carrying the
         per-user classification state forward to the next call."""
-        model = self.model
-        offset = model.offset
-        rp = model.rule_predictor
-        out: list[Sequence[PrefetchOp]] = [_NO_OPS] * len(requests)
+        with telemetry.span("vdc.hpm.plan"):
+            model = self.model
+            offset = model.offset
+            rp = model.rule_predictor
+            out: list[Sequence[PrefetchOp]] = [_NO_OPS] * len(requests)
 
-        by_user: dict[int, list[int]] = {}
-        for i, r in enumerate(requests):
-            by_user.setdefault(r.user_id, []).append(i)
+            by_user: dict[int, list[int]] = {}
+            for i, r in enumerate(requests):
+                by_user.setdefault(r.user_id, []).append(i)
 
-        # (slot, gaps_f32, last_ts, max_gap, req_ts, width, objs)
-        pending: list[tuple] = []
-        rule_memo = self._rule_memo
-        subscribed = self._subscribed
+            # (slot, gaps_f32, last_ts, max_gap, req_ts, width, objs)
+            pending: list[tuple] = []
+            rule_memo = self._rule_memo
+            subscribed = self._subscribed
 
-        for uid, idxs in by_user.items():
-            cached = self._users.get(uid)
-            if cached is None:
-                st = _UserState()
-                uniq: list[float] = []
-                gaps: list[float] = []
-                self._users[uid] = (st, uniq, gaps)
-            else:
-                st, uniq, gaps = cached
-            for i in idxs:
-                r = requests[i]
-                prev_len = len(st.timestamps)
-                _observe_classification(st, r)
-                if len(st.timestamps) != prev_len + 1:
-                    # history trim: rebuild the unique view
-                    uniq = sorted(set(st.timestamps))
-                    gaps = [b - a for a, b in zip(uniq, uniq[1:])]
-                elif not uniq or r.ts > uniq[-1]:
-                    if uniq:
-                        gaps.append(r.ts - uniq[-1])
-                    uniq.append(r.ts)
-                elif r.ts < uniq[-1]:
-                    # out-of-order arrival (traces are sorted; kept correct
-                    # for arbitrary input)
-                    j = bisect.bisect_left(uniq, r.ts)
-                    if j >= len(uniq) or uniq[j] != r.ts:
-                        uniq.insert(j, r.ts)
+            for uid, idxs in by_user.items():
+                cached = self._users.get(uid)
+                if cached is None:
+                    st = _UserState()
+                    uniq: list[float] = []
+                    gaps: list[float] = []
+                    self._users[uid] = (st, uniq, gaps)
+                else:
+                    st, uniq, gaps = cached
+                for i in idxs:
+                    r = requests[i]
+                    prev_len = len(st.timestamps)
+                    _observe_classification(st, r)
+                    if len(st.timestamps) != prev_len + 1:
+                        # history trim: rebuild the unique view
+                        uniq = sorted(set(st.timestamps))
                         gaps = [b - a for a, b in zip(uniq, uniq[1:])]
-                # else: duplicate of the latest timestamp — no change
+                    elif not uniq or r.ts > uniq[-1]:
+                        if uniq:
+                            gaps.append(r.ts - uniq[-1])
+                        uniq.append(r.ts)
+                    elif r.ts < uniq[-1]:
+                        # out-of-order arrival (traces are sorted; kept correct
+                        # for arbitrary input)
+                        j = bisect.bisect_left(uniq, r.ts)
+                        if j >= len(uniq) or uniq[j] != r.ts:
+                            uniq.insert(j, r.ts)
+                            gaps = [b - a for a, b in zip(uniq, uniq[1:])]
+                    # else: duplicate of the latest timestamp — no change
 
-                cls = st.classified
-                if cls == "realtime":
-                    key = (uid, r.obj)
-                    if key not in subscribed:
-                        subscribed.add(key)
-                        out[i] = [_stream_op(r, st)]
-                elif cls == "program":
-                    if len(uniq) < 4:
-                        continue
-                    med, max_gap, fast = _gap_stats(gaps)
-                    objs = st.last_cycle_objs or {r.obj}
-                    if fast:
-                        out[i] = _history_ops(r.ts, uid, offset,
-                                              st.last_window, objs,
-                                              uniq[-1] + med)
-                    else:
-                        pending.append(
-                            (i, np.asarray(gaps, np.float32), uniq[-1],
-                             max_gap, r.ts, st.last_window, objs))
-                elif cls == "human" and rp is not None:
-                    key = frozenset(st.recent_objs)
-                    preds = rule_memo.get(key, _MEMO_MISS)
-                    if preds is _MEMO_MISS:
-                        if len(rule_memo) >= _RULE_MEMO_MAX:
-                            rule_memo.clear()
-                        preds = rule_memo[key] = rp.predict(
-                            st.recent_objs, top_n=TOP_N_HUMAN)
-                    if preds:
-                        ts_l = st.timestamps
-                        gap = (ts_l[-1] - ts_l[-2]) if len(ts_l) >= 2 else 300.0
-                        out[i] = _rules_ops(r, offset, r.ts + gap, preds)
-            # uniq/gaps are rebound on trim/out-of-order branches: store the
-            # current bindings for the next window
-            self._users[uid] = (st, uniq, gaps)
+                    cls = st.classified
+                    if cls == "realtime":
+                        key = (uid, r.obj)
+                        if key not in subscribed:
+                            subscribed.add(key)
+                            out[i] = [_stream_op(r, st)]
+                    elif cls == "program":
+                        if len(uniq) < 4:
+                            continue
+                        med, max_gap, fast = _gap_stats(gaps)
+                        objs = st.last_cycle_objs or {r.obj}
+                        if fast:
+                            out[i] = _history_ops(r.ts, uid, offset,
+                                                  st.last_window, objs,
+                                                  uniq[-1] + med)
+                        else:
+                            pending.append(
+                                (i, np.asarray(gaps, np.float32), uniq[-1],
+                                 max_gap, r.ts, st.last_window, objs))
+                    elif cls == "human" and rp is not None:
+                        key = frozenset(st.recent_objs)
+                        preds = rule_memo.get(key, _MEMO_MISS)
+                        if preds is _MEMO_MISS:
+                            if len(rule_memo) >= _RULE_MEMO_MAX:
+                                rule_memo.clear()
+                            preds = rule_memo[key] = rp.predict(
+                                st.recent_objs, top_n=TOP_N_HUMAN)
+                        if preds:
+                            ts_l = st.timestamps
+                            gap = ((ts_l[-1] - ts_l[-2]) if len(ts_l) >= 2
+                                   else 300.0)
+                            out[i] = _rules_ops(r, offset, r.ts + gap, preds)
+                # uniq/gaps are rebound on trim/out-of-order branches:
+                # store the current bindings for the next window
+                self._users[uid] = (st, uniq, gaps)
 
-        if pending:
-            forecasts = model.arima.batched_forecast([t[1] for t in pending])
-            for (i, _, last, max_gap, r_ts, width, objs), g in zip(
-                    pending, forecasts):
-                next_ts = clamp_forecast_gap(last, float(g), max_gap)
-                out[i] = _history_ops(r_ts, requests[i].user_id, offset,
-                                      width, objs, next_ts)
-        return out
+            if pending:
+                forecasts = model.arima.batched_forecast(
+                    [t[1] for t in pending])
+                for (i, _, last, max_gap, r_ts, width, objs), g in zip(
+                        pending, forecasts):
+                    next_ts = clamp_forecast_gap(last, float(g), max_gap)
+                    out[i] = _history_ops(r_ts, requests[i].user_id, offset,
+                                          width, objs, next_ts)
+            return out
 
 
 def build_rule_transactions(

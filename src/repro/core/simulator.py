@@ -28,6 +28,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.cache import (Cache, CacheStats, chunk_bytes, chunks_for_range,
                               make_cache)
 from repro.core.delivery import Prefetcher
@@ -689,22 +690,24 @@ def run_strategy(
     """
     from repro.core.delivery import make_prefetcher
 
-    pf = make_prefetcher(strategy, grid, training_requests)
-    use_cache = strategy != "no_cache"
-    # "Cache Only" is the paper's no-optimization baseline: a cache layer
-    # but no pre-fetching AND no placement strategy
-    if strategy in ("no_cache", "cache_only"):
-        config = dataclasses.replace(config, enable_placement=False)
-    if engine == "reference":
-        sim = VDCSimulator(grid, pf, config, use_cache=use_cache)
-    elif engine == "vector":
-        from repro.core.engine import VectorVDCSimulator
+    with telemetry.job():
+        with telemetry.span("vdc.delivery.train"):
+            pf = make_prefetcher(strategy, grid, training_requests)
+        use_cache = strategy != "no_cache"
+        # "Cache Only" is the paper's no-optimization baseline: a cache
+        # layer but no pre-fetching AND no placement strategy
+        if strategy in ("no_cache", "cache_only"):
+            config = dataclasses.replace(config, enable_placement=False)
+        if engine == "reference":
+            sim = VDCSimulator(grid, pf, config, use_cache=use_cache)
+        elif engine == "vector":
+            from repro.core.engine import VectorVDCSimulator
 
-        sim = VectorVDCSimulator(grid, pf, config, use_cache=use_cache)
-    elif engine == "interval":
-        from repro.core.engine import IntervalVDCSimulator
+            sim = VectorVDCSimulator(grid, pf, config, use_cache=use_cache)
+        elif engine == "interval":
+            from repro.core.engine import IntervalVDCSimulator
 
-        sim = IntervalVDCSimulator(grid, pf, config, use_cache=use_cache)
-    else:
-        raise ValueError(f"unknown engine: {engine!r}")
-    return sim.run(requests, name=strategy)
+            sim = IntervalVDCSimulator(grid, pf, config, use_cache=use_cache)
+        else:
+            raise ValueError(f"unknown engine: {engine!r}")
+        return sim.run(requests, name=strategy)
