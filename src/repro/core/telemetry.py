@@ -34,8 +34,9 @@ costs about two clock reads.
 Counters are always on and coarse (per loop, per bank flush); their totals
 are in :func:`counters`, and while a trace runs they are also in the
 ``meta`` of the span that counted them: ``requests``, ``prefetch_events``,
-``push_events`` and ``absorbed`` per loop or drain, ``bank_calls``,
-``bank_rows`` and ``bank_pad_rows`` per bank flush.
+``prefetch_noop`` (prefetch ops that found every finalized chunk cached, or
+none finalized), ``push_events`` and ``absorbed`` per loop or drain,
+``bank_calls``, ``bank_rows`` and ``bank_pad_rows`` per bank flush.
 """
 from __future__ import annotations
 
