@@ -2,19 +2,26 @@
 the timed path; the rest of the run (set-up, window, check) is the
 harness's own.
 
-    python fault_run.py <fault> <size> --workload <cell> --seed <n> \
-        --seconds <s>
+    python fault_run.py <fault> <size> [--strategy <s>] --workload <cell> \
+        --seed <n> --seconds <s>
 
 ``size`` is ``tiny`` (the harness's ``--rehearse`` sizes) or ``cell`` (the
 cell's own sizes and check, for faults that the tiny size is too small to
 show; give ``--seconds`` room for a job to reach the checked prefix).
+``--strategy`` runs the cell's spec under another strategy (for instance
+``cache_only``, which the engine serves through its static block replay,
+``_run_static``), with the rest of the spec as the cell has it.
 
 Faults: ``none``; ``state_unchanged`` (the serving step leaves every
-cache as it was: inserts are dropped); ``half_batch`` (each ARIMA bank
-call answers the first half of its real rows and gives the other real rows
-their mean; padding rows are left alone);
+cache as it was: inserts and block commits are dropped); ``half_batch``
+(each ARIMA bank call answers the first half of its real rows and gives
+the other real rows their mean; padding rows are left alone);
 ``answer_altered`` (one request's local bytes are off by one where the
-engine writes them).  The cell runs on one chip, so the fault of a
+engine writes them); ``static_answer_altered`` (the eighth request of
+each static window has its local bytes off by one once the window is
+served); ``static_counter_altered`` (from its first static window on, the
+engine reports one eviction more on its lowest DTN than it made; the
+outcomes are untouched).  The cell runs on one chip, so the fault of a
 left-out exchange between chips does not arise.
 """
 import os
@@ -35,6 +42,7 @@ def plant(fault: str) -> None:
         cache.IntLRUState.insert_one = lambda self, k, size: None
         cache.IntLRUState.upsert_seq = lambda self, keys, size_each: None
         cache.IntLRUState.upsert_batch = lambda self, keys, size_each: None
+        cache.IntLRUState.commit_unique = lambda self, *args: None
     elif fault == "half_batch":
         import numpy as np
 
@@ -71,16 +79,56 @@ def plant(fault: str) -> None:
                 self._o_loc[idx] += 1
 
         VectorVDCSimulator._serve_event = altered
+    elif fault == "static_answer_altered":
+        from repro.core.engine import VectorVDCSimulator
+
+        run_static = VectorVDCSimulator._run_static
+
+        def altered(self, A):
+            run_static(self, A)
+            if len(A["arr"]) > 7:
+                self._o_loc[7] += 1
+
+        VectorVDCSimulator._run_static = altered
+    elif fault == "static_counter_altered":
+        from repro.core.engine import VectorVDCSimulator
+
+        run_static = VectorVDCSimulator._run_static
+
+        def miscounting(self, A):
+            run_static(self, A)
+            if not getattr(self, "_miscounted", False):
+                self._miscounted = True
+                self.caches[min(self.caches)].evictions += 1
+
+        VectorVDCSimulator._run_static = miscounting
     elif fault != "none":
         raise SystemExit(f"unknown fault {fault!r}")
 
 
+def set_size(harness, size: str, strategy: str | None) -> None:
+    """Make the harness's ``--rehearse`` run at ``size``, under
+    ``strategy`` where one is given."""
+    if size == "cell":
+        sized = lambda spec: spec  # noqa: E731
+    elif size == "tiny":
+        sized = harness.rehearse_spec
+    else:
+        raise SystemExit(f"unknown size {size!r}")
+    if strategy is None:
+        harness.rehearse_spec = sized
+    else:
+        harness.rehearse_spec = lambda spec: {**sized(spec),
+                                              "strategy": strategy}
+
+
 if __name__ == "__main__":
+    argv = sys.argv[3:]
+    strategy = None
+    if argv[:1] == ["--strategy"]:
+        strategy, argv = argv[1], argv[2:]
     plant(sys.argv[1])
     from vdcbench import harness
 
-    if sys.argv[2] == "cell":
-        harness.rehearse_spec = lambda spec: spec
-    elif sys.argv[2] != "tiny":
-        raise SystemExit(f"unknown size {sys.argv[2]!r}")
-    sys.exit(harness.main(sys.argv[3:] + ["--rehearse"], time.perf_counter()))
+    set_size(harness, sys.argv[2], strategy)
+    sys.exit(harness.main(argv + ["--rehearse"], time.perf_counter()))

@@ -16,7 +16,9 @@ serve three purposes:
 - capturing what the timed path produced for the correctness check: the
   per-request outcome columns and planned ops of the one job the harness
   arms, and the engine's integer counters at the end of each of its stream
-  windows.
+  windows, whichever path served the window (the dynamic event loop,
+  ``VectorVDCSimulator._run_dyn_window``, or the static block replay,
+  ``VectorVDCSimulator._run_static``).
 """
 from __future__ import annotations
 
@@ -63,6 +65,13 @@ class Probes:
     @property
     def ops(self) -> list | None:
         return self._ops or None
+
+    def _window_served(self, sim, A: dict, stream_engine) -> None:
+        """Keep the engine's counters as they stand once a stream window is
+        served, keyed by the requests served so far."""
+        if self._capturing:
+            self._seen += len(A["arr"])
+            self._counters[self._seen] = _counters_of(sim, stream_engine)
 
     def _span(self, layer: str, fn, *args, **kw):
         with jax.profiler.TraceAnnotation("vdc." + layer):
@@ -119,12 +128,17 @@ class Probes:
 
         def windowed(sim, A, stream_engine, *args):
             run_dyn_window(sim, A, stream_engine, *args)
-            if probes._capturing:
-                probes._seen += len(A["arr"])
-                probes._counters[probes._seen] = _counters_of(
-                    sim, stream_engine)
+            probes._window_served(sim, A, stream_engine)
 
         VectorVDCSimulator._run_dyn_window = windowed
+
+        run_static = VectorVDCSimulator._run_static
+
+        def static_windowed(sim, A):
+            run_static(sim, A)
+            probes._window_served(sim, A, None)
+
+        VectorVDCSimulator._run_static = static_windowed
 
         if not self.spans_on:
             return
