@@ -2,15 +2,19 @@
 the timed path; the rest of the run (set-up, window, check) is the
 harness's own.
 
-    python fault_run.py <fault> <size> [--strategy <s>] --workload <cell> \
-        --seed <n> --seconds <s>
+    python fault_run.py <fault> <size> [--strategy <s>] [--md2-bank] \
+        --workload <cell> --seed <n> --seconds <s>
 
 ``size`` is ``tiny`` (the harness's ``--rehearse`` sizes) or ``cell`` (the
 cell's own sizes and check, for faults that the tiny size is too small to
 show; give ``--seconds`` room for a job to reach the checked prefix).
 ``--strategy`` runs the cell's spec under another strategy (for instance
 ``cache_only``, which the engine serves through its static block replay,
-``_run_static``), with the rest of the spec as the cell has it.
+``_run_static``, or ``md2``, which predicts online inside the event loop),
+with the rest of the spec as the cell has it.  ``--md2-bank`` gives
+``md2``'s model the fixed-width ARIMA bank (``ARIMA(n=60, bank=True)``) in
+place of its single-series program (``bank=False``), the semantics that the
+reference has and that a window planner for ``md2`` needs.
 
 Faults: ``none``; ``state_unchanged`` (the serving step leaves every
 cache as it was: inserts and block commits are dropped); ``half_batch``
@@ -21,8 +25,10 @@ engine writes them); ``static_answer_altered`` (the eighth request of
 each static window has its local bytes off by one once the window is
 served); ``static_counter_altered`` (from its first static window on, the
 engine reports one eviction more on its lowest DTN than it made; the
-outcomes are untouched).  The cell runs on one chip, so the fault of a
-left-out exchange between chips does not arise.
+outcomes are untouched); ``md2_op_dropped`` (the eighth ``observe`` call
+of each ``md2`` job loses its last op where the adapter produces it).  The
+cell runs on one chip, so the fault of a left-out exchange between chips
+does not arise.
 """
 import os
 import sys
@@ -102,8 +108,33 @@ def plant(fault: str) -> None:
                 self.caches[min(self.caches)].evictions += 1
 
         VectorVDCSimulator._run_static = miscounting
+    elif fault == "md2_op_dropped":
+        from repro.core.delivery import MD2Adapter
+
+        observe = MD2Adapter.observe
+
+        def dropping(self, r):
+            ops = observe(self, r)
+            self._observed = getattr(self, "_observed", 0) + 1
+            return ops[:-1] if self._observed == 8 else ops
+
+        MD2Adapter.observe = dropping
     elif fault != "none":
         raise SystemExit(f"unknown fault {fault!r}")
+
+
+def force_md2_bank() -> None:
+    """Give ``md2``'s model the fixed-width ARIMA bank."""
+    from repro.core import mining
+    from repro.core.arima import ARIMA
+
+    init = mining.MeshRulePredictor.__init__
+
+    def banked(self, *args, **kw):
+        init(self, *args, **kw)
+        self.arima = ARIMA(n=self.history, bank=True)
+
+    mining.MeshRulePredictor.__init__ = banked
 
 
 def set_size(harness, size: str, strategy: str | None) -> None:
@@ -127,6 +158,9 @@ if __name__ == "__main__":
     strategy = None
     if argv[:1] == ["--strategy"]:
         strategy, argv = argv[1], argv[2:]
+    if argv[:1] == ["--md2-bank"]:
+        argv = argv[1:]
+        force_md2_bank()
     plant(sys.argv[1])
     from vdcbench import harness
 

@@ -3,14 +3,14 @@ reference (:mod:`vdcbench.ref`), request by request and counter by counter.
 
 The program's side is captured from the first job of the measured window
 (:class:`vdcbench.probes.Probes`): every request's outcome columns and
-planned prefetch ops, and the engine's integer counters at the end of each
-stream window.  The reference replays the same replay split, online, over
+prefetch ops (planned in windows or predicted online), and the engine's
+integer counters at the end of each stream window.  The reference replays the same replay split, online, over
 the cell's first ``check.windows`` stream windows and stops right after
 the last request of that prefix, where the program's counters were read.
 The numbers compared, each against its own limit:
 
 - ``missing``: requests of the prefix the program produced no answer for;
-- ``ops_differ``: requests whose planned ops (issue time, user, object,
+- ``ops_differ``: requests whose prefetch ops (issue time, user, object,
   time range, reason — stream hand-offs included) are not exactly the
   reference's;
 - ``outcomes_differ``: requests whose integer outcome (bytes served, and

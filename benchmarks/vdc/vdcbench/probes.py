@@ -13,12 +13,23 @@ serve three purposes:
   Each span is also written into the profiler's trace as a
   ``jax.profiler.TraceAnnotation`` named ``vdc.<layer>``, so that idle time
   on the device can be blamed on the host work running during it;
-- capturing what the timed path produced for the correctness check: the
-  per-request outcome columns and planned ops of the one job the harness
-  arms, and the engine's integer counters at the end of each of its stream
-  windows, whichever path served the window (the dynamic event loop,
+- capturing what the timed path produced for the correctness check, in the
+  one job the harness arms: the per-request outcome columns, the engine's
+  integer counters at the end of each of its stream windows, whichever
+  path served the window (the dynamic event loop,
   ``VectorVDCSimulator._run_dyn_window``, or the static block replay,
-  ``VectorVDCSimulator._run_static``).
+  ``VectorVDCSimulator._run_static``), and each request's prefetch ops,
+  however they were produced:
+
+  - by a window planner: the per-request op lists that the planner hands
+    to ``delivery._route_planned_ops``, the routing that turns them into
+    scheduled prefetches and stream subscriptions.  A planner is checked
+    only if it routes its windows through that function;
+  - online, where ``_run_dyn_window`` runs with no planner: the list that
+    the simulator's prefetcher returns from each ``observe`` call, in
+    request order.  An adapter that turns some ops into subscriptions
+    inside ``observe`` (``hpm`` without batched prediction, which no cell
+    runs) returns only the rest, so its stream hand-offs are not seen.
 """
 from __future__ import annotations
 
@@ -44,7 +55,7 @@ class Probes:
 
     def capture(self, on: bool) -> None:
         """Start (clearing what was kept) or stop keeping every window's
-        outcome columns, planned ops and closing counters."""
+        outcome columns, prefetch ops and closing counters."""
         if on:
             self._cols, self._ops, self._counters = [], [], {}
             self._seen = 0
@@ -101,18 +112,14 @@ class Probes:
 
         arima._compiled_bank = counted_bank
 
-        plan_window = hpm.BatchedHPMPlanner.plan_window
+        route_planned_ops = delivery._route_planned_ops
 
-        def planned(planner, requests):
-            if probes.spans_on:
-                out = probes._span("plan", plan_window, planner, requests)
-            else:
-                out = plan_window(planner, requests)
+        def routed(requests, per_req):
             if probes._capturing:
-                probes._ops.extend(out)
-            return out
+                probes._ops.extend(per_req)
+            return route_planned_ops(requests, per_req)
 
-        hpm.BatchedHPMPlanner.plan_window = planned
+        delivery._route_planned_ops = routed
 
         add_columns = simulator.OutcomeAggregate.add_columns
 
@@ -126,8 +133,24 @@ class Probes:
 
         run_dyn_window = VectorVDCSimulator._run_dyn_window
 
-        def windowed(sim, A, stream_engine, *args):
-            run_dyn_window(sim, A, stream_engine, *args)
+        def windowed(sim, A, stream_engine, heap, counter, planner):
+            if planner is not None or not probes._capturing:
+                run_dyn_window(sim, A, stream_engine, heap, counter, planner)
+            else:
+                pf = sim.pf
+                observe = pf.observe
+
+                def observed(r):
+                    ops = observe(r)
+                    probes._ops.append(ops)
+                    return ops
+
+                pf.observe = observed
+                try:
+                    run_dyn_window(sim, A, stream_engine, heap, counter,
+                                   planner)
+                finally:
+                    del pf.observe
             probes._window_served(sim, A, stream_engine)
 
         VectorVDCSimulator._run_dyn_window = windowed
@@ -148,6 +171,13 @@ class Probes:
             return probes._span("train", make_prefetcher, *args, **kw)
 
         delivery.make_prefetcher = trained
+
+        plan_window = hpm.BatchedHPMPlanner.plan_window
+
+        def planned(planner, requests):
+            return probes._span("plan", plan_window, planner, requests)
+
+        hpm.BatchedHPMPlanner.plan_window = planned
 
         run_placement = VectorVDCSimulator._run_placement
 

@@ -2,9 +2,9 @@
 
 Frozen copy of the online half of ``src/repro/core/hpm.py`` (classification
 state machine, history / rules / stream predictions, ``HybridPrefetcher``)
-and of ``HPMAdapter``, ``NoPrefetch`` and ``make_prefetcher`` from
-``src/repro/core/delivery.py``, at commit bcb7c9a.  Every request is
-observed one at a time; every history forecast is one padded bank call.
+and of ``HPMAdapter`` and ``NoPrefetch`` from ``src/repro/core/delivery.py``,
+at commit bcb7c9a.  Every request is observed one at a time; every history
+forecast is one padded bank call.
 
 ``arima_dtype`` selects the bank's precision (``float32`` as configured;
 ``bfloat16`` for the correctness control).
@@ -191,12 +191,3 @@ class HPMAdapter:
                 out.append(op)
         return out
 
-
-def make_prefetcher(kind: str, training_requests=None,
-                    arima_dtype: str = "float32"):
-    kind = kind.lower()
-    if kind in ("none", "cache_only", "no_cache"):
-        return NoPrefetch()
-    if kind == "hpm":
-        return HPMAdapter(training_requests, arima_dtype=arima_dtype)
-    raise ValueError(f"the reference has no prefetcher {kind!r}")

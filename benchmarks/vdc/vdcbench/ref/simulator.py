@@ -3,10 +3,12 @@ of the VDC delivery framework (paper §V-A1), predicting online.
 
 Frozen copy of ``VDCSimulator`` (materialized replay), ``SimConfig``,
 ``RequestOutcome``, ``_OriginQueue`` and the WAN constants of
-``src/repro/core/simulator.py`` at commit bcb7c9a.  It imports nothing of
-the program.  :func:`replay` returns every request's outcome and the ops
-the prediction model emitted for it, in trace order, and the replay's
-integer counters where it stopped.
+``src/repro/core/simulator.py``, and of ``make_prefetcher`` from
+``src/repro/core/delivery.py``, at commit bcb7c9a; its ``md2`` builds the
+copy in :mod:`.mining`.  It imports nothing of the program.
+:func:`replay` returns every request's outcome and the ops the prediction
+emitted for it, in trace order, and the replay's integer counters where it
+stopped.
 """
 from __future__ import annotations
 
@@ -20,7 +22,8 @@ from typing import Sequence
 import numpy as np
 
 from .cache import Cache, chunk_bytes, chunks_for_range, make_cache
-from .hpm import PrefetchOp, make_prefetcher
+from .hpm import HPMAdapter, NoPrefetch, PrefetchOp
+from .mining import MD2Adapter
 from .placement import PlacementEngine
 
 GBPS = 1e9 / 8  # bytes per second per Gbps
@@ -312,36 +315,46 @@ def counters_of(caches: dict, stream_engine) -> dict:
     return out
 
 
+def make_prefetcher(kind: str, grid, training_requests=None,
+                    arima_dtype: str = "float32"):
+    """The reference's prefetcher of ``kind``, built from the grid and the
+    training split as the program's ``delivery.make_prefetcher`` builds
+    it."""
+    kind = kind.lower()
+    if kind in ("none", "cache_only", "no_cache"):
+        return NoPrefetch()
+    if kind == "hpm":
+        return HPMAdapter(training_requests, arima_dtype=arima_dtype)
+    if kind == "md2":
+        return MD2Adapter(grid.n_locs, training_requests,
+                          arima_dtype=arima_dtype)
+    raise ValueError(f"the reference has no prefetcher {kind!r}")
+
+
 def replay(strategy: str, requests: Sequence, grid, config: SimConfig,
            training_requests=None, arima_dtype: str = "float32"):
     """Replay ``requests`` with ``strategy`` and stop right after the last
     one is served, before any later event; returns ``(outcomes, ops,
     counters)``: per request, its :class:`RequestOutcome` and the ops the
-    prediction model emitted for it (stream hand-offs included), and
-    :func:`counters_of` at that point."""
-    pf = make_prefetcher(strategy, training_requests, arima_dtype=arima_dtype)
+    prediction emitted for it, and :func:`counters_of` at that point.
+    For ``hpm`` the ops are its model's, stream hand-offs included (the
+    adapter turns those into subscriptions); for the others, what the
+    adapter returns."""
+    pf = make_prefetcher(strategy, grid, training_requests,
+                         arima_dtype=arima_dtype)
     use_cache = strategy != "no_cache"
     if strategy in ("no_cache", "cache_only"):
         config = dataclasses.replace(config, enable_placement=False)
     ops: list[list[PrefetchOp]] = []
-    model = getattr(pf, "model", None)
-    if model is not None:
-        observe = model.observe
+    emitter = pf.model if isinstance(pf, HPMAdapter) else pf
+    observe = emitter.observe
 
-        def logged(r):
-            out = observe(r)
-            ops.append(out)
-            return out
+    def logged(r):
+        out = observe(r)
+        ops.append(out)
+        return out
 
-        model.observe = logged
-    else:
-        observe_none = pf.observe
-
-        def logged_none(r):
-            ops.append([])
-            return observe_none(r)
-
-        pf.observe = logged_none
+    emitter.observe = logged
     served = itertools.count(1)
     n = len(requests)
 
