@@ -302,9 +302,13 @@ def _gap_stats(g: list[float]) -> tuple[float, float, bool]:
 
     The gap window is ≤ a couple hundred points and this runs once per
     observed request: plain-Python median/std beat the NumPy dispatch
-    overhead by ~20x here.  Shared by the online and batched prediction
-    paths so the near-constant-gap decision below is bitwise identical in
-    both (a vectorized reimplementation could flip a knife-edge series).
+    overhead by ~20x here.  This is the one definition of the
+    near-constant-gap decision below: the online path and
+    ``predict_next_timestamp(s)`` call it per request, and the batched
+    planner (``repro.core.hpm._PlannedUser.gap_stats``) decides from
+    running sums only outside a proven rounding margin of the threshold
+    and calls it inside, so every path decides bit for bit alike (an
+    unguarded vectorized reimplementation could flip a knife-edge series).
 
     Near-constant inter-arrivals (scripted cron-style consumers): ARIMA's
     forecast collapses to the median gap; skip the fit.  This is the common

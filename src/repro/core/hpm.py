@@ -218,6 +218,89 @@ class HybridPrefetcher:
         return self.users[user_id].classified if user_id in self.users else "unknown"
 
 
+class _PlannedUser:
+    """One user's planner state: the classification state machine, the
+    sorted-unique timestamps ``uniq`` with their gap list ``gaps`` (what
+    the ARIMA bank is fed), and the running statistics of the fast-path
+    decision, so a program request does not re-sort and re-sum ``gaps``.
+
+    ``gsorted`` holds the gaps in sorted order, so the median and largest
+    gap are those of ``sorted(gaps)``; ``s1`` and ``s2`` are the running
+    sums of the gaps and of their squares.  Appends update them; the trim
+    and out-of-order branches, which rebuild ``gaps``, recompute them from
+    scratch.  ``stats`` is the last decision, reused while ``gaps`` is
+    unchanged (a duplicate timestamp)."""
+
+    __slots__ = ("st", "uniq", "gaps", "gsorted", "s1", "s2", "stats")
+
+    def __init__(self):
+        self.st = _UserState()
+        self.uniq: list[float] = []
+        self._rebuild([])
+
+    def _rebuild(self, gaps: list[float]) -> None:
+        self.gaps = gaps
+        self.gsorted = sorted(gaps)
+        self.s1 = sum(gaps)
+        self.s2 = sum(x * x for x in gaps)
+        self.stats = None
+
+    def observe(self, r: Request) -> None:
+        """Feed one request through the classification state machine and
+        bring the unique timestamps and gap statistics up to date."""
+        st, uniq = self.st, self.uniq
+        prev_len = len(st.timestamps)
+        _observe_classification(st, r)
+        if len(st.timestamps) != prev_len + 1:
+            # history trim: rebuild the unique view
+            self.uniq = uniq = sorted(set(st.timestamps))
+            self._rebuild([b - a for a, b in zip(uniq, uniq[1:])])
+        elif not uniq or r.ts > uniq[-1]:
+            if uniq:
+                g = r.ts - uniq[-1]
+                self.gaps.append(g)
+                bisect.insort(self.gsorted, g)
+                self.s1 += g
+                self.s2 += g * g
+                self.stats = None
+            uniq.append(r.ts)
+        elif r.ts < uniq[-1]:
+            # out-of-order arrival (traces are sorted; kept correct for
+            # arbitrary input)
+            j = bisect.bisect_left(uniq, r.ts)
+            if j >= len(uniq) or uniq[j] != r.ts:
+                uniq.insert(j, r.ts)
+                self._rebuild([b - a for a, b in zip(uniq, uniq[1:])])
+        # else: duplicate of the latest timestamp — no change
+
+    def gap_stats(self) -> tuple[float, float, bool, bool]:
+        """``_gap_stats(gaps)`` plus whether this call fell back to it.
+
+        With ``n`` gaps, ``var = s2/n − (s1/n)²`` and the fast-path
+        threshold ``th = (0.02·med)²`` (``std/med < 0.02`` squared), the
+        decision is ``var < th`` wherever ``|var − th|`` exceeds
+        ``1e-9·s2/n``.  Running sums of n ≤ 199 positive terms err by about
+        ``(3n+5)·2⁻⁵³·s2/n`` ≈ 7e-14·s2/n, and ``_gap_stats``'s own
+        ``std/med`` by as little, so outside that margin both decide alike;
+        inside it (and for ``med ≤ 0``) ``_gap_stats`` decides."""
+        if self.stats is not None:
+            return (*self.stats, False)
+        gs = self.gsorted
+        n = len(gs)
+        mid = n // 2
+        med = gs[mid] if n % 2 else (gs[mid - 1] + gs[mid]) / 2.0
+        if med > 0:
+            m2 = self.s2 / n
+            mean = self.s1 / n
+            var = m2 - mean * mean
+            th = (0.02 * med) ** 2
+            if abs(var - th) > 1e-9 * m2:
+                self.stats = (med, gs[-1], var < th)
+                return (*self.stats, False)
+        self.stats = _gap_stats(self.gaps)
+        return (*self.stats, True)
+
+
 _NO_OPS: tuple = ()
 _MEMO_MISS = object()
 # rule-prediction memo bound: predictions are pure in the recent-object
@@ -238,10 +321,17 @@ class BatchedHPMPlanner:
       user and each user's sequence is replayed through the shared
       classification state machine.  A sorted-unique timestamp array and its
       gap series are maintained *incrementally* (the online path re-sorts
-      per request), near-constant-gap predictions resolve immediately via
-      the shared :func:`repro.core.arima._gap_stats`, rule predictions are
+      per request), together with the gaps in sorted order and running
+      sums of the gaps and their squares (:class:`_PlannedUser`).
+      Near-constant-gap predictions resolve immediately: the median-fast-
+      path decision is taken from the running sums wherever it lies
+      outside a proven rounding margin, and from the shared
+      :func:`repro.core.arima._gap_stats` — the one definition — inside
+      it, so every decision equals ``_gap_stats``'s.  Rule predictions are
       memoized on the (frozen) recent-object set, and noisy-gap histories
-      are deferred as ARIMA tasks.
+      are deferred as ARIMA tasks.  The ``vdc.hpm.plan`` span counts
+      ``gap_decisions`` (program-request decisions) and ``gap_exact``
+      (those that fell back to ``_gap_stats``).
     - **phase 2 — bank flush**: all deferred gap series go through
       :meth:`ARIMA.batched_forecast` — ``BANK_WIDTH`` users per compiled
       vmap call — and the resulting ops are written back to their request
@@ -266,9 +356,9 @@ class BatchedHPMPlanner:
 
     def __init__(self, model: HybridPrefetcher):
         self.model = model
-        # per-user (st, uniq, gaps): uniq == sorted(set(st.timestamps)),
-        # gaps == np.diff(uniq) — maintained incrementally across windows
-        self._users: dict[int, tuple[_UserState, list[float], list[float]]] = {}
+        # per-user classification state and gap statistics, carried
+        # across windows
+        self._users: dict[int, _PlannedUser] = {}
         self._rule_memo: dict[frozenset, list] = {}
         self._subscribed: set[tuple[int, int]] = set()
 
@@ -281,7 +371,7 @@ class BatchedHPMPlanner:
                     ) -> list[Sequence[PrefetchOp]]:
         """Plan one timestamp-ordered window of the trace, carrying the
         per-user classification state forward to the next call."""
-        with telemetry.span("vdc.hpm.plan"):
+        with telemetry.span("vdc.hpm.plan") as sp:
             model = self.model
             offset = model.offset
             rp = model.rule_predictor
@@ -296,36 +386,16 @@ class BatchedHPMPlanner:
             rule_memo = self._rule_memo
             subscribed = self._subscribed
 
+            users = self._users
+            n_decisions = n_exact = 0
             for uid, idxs in by_user.items():
-                cached = self._users.get(uid)
-                if cached is None:
-                    st = _UserState()
-                    uniq: list[float] = []
-                    gaps: list[float] = []
-                    self._users[uid] = (st, uniq, gaps)
-                else:
-                    st, uniq, gaps = cached
+                u = users.get(uid)
+                if u is None:
+                    u = users[uid] = _PlannedUser()
+                st = u.st
                 for i in idxs:
                     r = requests[i]
-                    prev_len = len(st.timestamps)
-                    _observe_classification(st, r)
-                    if len(st.timestamps) != prev_len + 1:
-                        # history trim: rebuild the unique view
-                        uniq = sorted(set(st.timestamps))
-                        gaps = [b - a for a, b in zip(uniq, uniq[1:])]
-                    elif not uniq or r.ts > uniq[-1]:
-                        if uniq:
-                            gaps.append(r.ts - uniq[-1])
-                        uniq.append(r.ts)
-                    elif r.ts < uniq[-1]:
-                        # out-of-order arrival (traces are sorted; kept correct
-                        # for arbitrary input)
-                        j = bisect.bisect_left(uniq, r.ts)
-                        if j >= len(uniq) or uniq[j] != r.ts:
-                            uniq.insert(j, r.ts)
-                            gaps = [b - a for a, b in zip(uniq, uniq[1:])]
-                    # else: duplicate of the latest timestamp — no change
-
+                    u.observe(r)
                     cls = st.classified
                     if cls == "realtime":
                         key = (uid, r.obj)
@@ -333,9 +403,12 @@ class BatchedHPMPlanner:
                             subscribed.add(key)
                             out[i] = [_stream_op(r, st)]
                     elif cls == "program":
+                        uniq = u.uniq
                         if len(uniq) < 4:
                             continue
-                        med, max_gap, fast = _gap_stats(gaps)
+                        med, max_gap, fast, exact = u.gap_stats()
+                        n_decisions += 1
+                        n_exact += exact
                         objs = st.last_cycle_objs or {r.obj}
                         if fast:
                             out[i] = _history_ops(r.ts, uid, offset,
@@ -343,7 +416,7 @@ class BatchedHPMPlanner:
                                                   uniq[-1] + med)
                         else:
                             pending.append(
-                                (i, np.asarray(gaps, np.float32), uniq[-1],
+                                (i, np.asarray(u.gaps, np.float32), uniq[-1],
                                  max_gap, r.ts, st.last_window, objs))
                     elif cls == "human" and rp is not None:
                         key = frozenset(st.recent_objs)
@@ -358,9 +431,7 @@ class BatchedHPMPlanner:
                             gap = ((ts_l[-1] - ts_l[-2]) if len(ts_l) >= 2
                                    else 300.0)
                             out[i] = _rules_ops(r, offset, r.ts + gap, preds)
-                # uniq/gaps are rebound on trim/out-of-order branches:
-                # store the current bindings for the next window
-                self._users[uid] = (st, uniq, gaps)
+            sp.count(gap_decisions=n_decisions, gap_exact=n_exact)
 
             if pending:
                 forecasts = model.arima.batched_forecast(

@@ -31,12 +31,15 @@ push emission).  They are taken by timed wrappers chosen once per loop, so
 the loop carries no per-event branch; with a trace running each timed call
 costs about two clock reads.
 
-Counters are always on and coarse (per loop, per bank flush); their totals
-are in :func:`counters`, and while a trace runs they are also in the
-``meta`` of the span that counted them: ``requests``, ``prefetch_events``,
-``prefetch_noop`` (prefetch ops that found every finalized chunk cached, or
-none finalized), ``push_events`` and ``absorbed`` per loop or drain,
-``bank_calls``, ``bank_rows`` and ``bank_pad_rows`` per bank flush.
+Counters are always on and coarse (per loop, per plan window, per bank
+flush); their totals are in :func:`counters`, and while a trace runs they
+are also in the ``meta`` of the span that counted them: ``requests``,
+``prefetch_events``, ``prefetch_noop`` (prefetch ops that found every
+finalized chunk cached, or none finalized), ``push_events`` and
+``absorbed`` per loop or drain, ``gap_decisions`` (program-request
+fast-path decisions) and ``gap_exact`` (those decided by ``_gap_stats``
+inside the running sums' margin) per plan window, ``bank_calls``,
+``bank_rows`` and ``bank_pad_rows`` per bank flush.
 """
 from __future__ import annotations
 
