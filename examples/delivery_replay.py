@@ -23,8 +23,7 @@ def main():
     ap.add_argument("--engine", default="vector",
                     choices=["vector", "interval", "reference"],
                     help="replay engine (vector = array batch-replay, "
-                         "interval = interval-algebra presence + sharded "
-                         "multi-DTN driver, "
+                         "interval = interval-algebra presence tracking, "
                          "reference = per-chunk dict/heap baseline)")
     args = ap.parse_args()
 

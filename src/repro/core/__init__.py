@@ -13,7 +13,7 @@ from repro.core.classify import (classify_request_type, classify_users,
                                  fresh_duplicate_bytes, summarize_trace)
 from repro.core.delivery import (HPMAdapter, MD1Adapter, MD2Adapter,
                                  NoPrefetch, PeerFetchRange,
-                                 coalesce_peer_fetches, make_prefetcher,
+                                 coalesce_peer_ranges, make_prefetcher,
                                  select_peer_sources)
 from repro.core.fpgrowth import RulePredictor, association_rules, frequent_itemsets
 from repro.core.hpm import (BatchedHPMPlanner, HybridPrefetcher, PrefetchOp,

@@ -936,7 +936,7 @@ class IntervalLRUState:
       the hit path.
 
     LRU order: every touch/insert of a chunk run appends one record
-    ``(rid, obj, lo, hi, src)`` to a FIFO; rids increase monotonically and
+    ``(rid, obj, lo, hi)`` to a FIFO; rids increase monotonically and
     recency increases with chunk id inside a record — exactly the
     reference's per-chunk stamp order (hits touched in ascending chunk
     order, then misses inserted ascending).  A record is valid for the
@@ -945,23 +945,15 @@ class IntervalLRUState:
     ascending order, splitting segments when only part is needed.
 
     Used by the interval replay engine's static serving path (one instance
-    per DTN, replayable independently per DTN for the sharded driver).  The
-    ``*_log`` lists record the side effects phase B of that engine needs:
-    miss ranges (peer/origin accounting), insert/evict ranges (presence
-    timelines for peer lookups) and eviction split events (exactness audit
-    for peer-vs-origin insert order — see ``engine.IntervalVDCSimulator``).
+    per DTN).
     """
 
     policy = "lru"
 
-    def __init__(self, capacity_bytes: int, log_events: bool = True):
+    def __init__(self, capacity_bytes: int):
         self.capacity = int(capacity_bytes)
         self.used = 0
         self.n_live = 0
-        # event logging feeds the sharded driver's phase B (presence
-        # timelines + exactness audit); the sequential sweep resolves peers
-        # inline and turns it off
-        self._log = log_events
         self._objs: dict[int, list] = {}     # recency map buckets
         self._sizes: dict[int, list] = {}    # size map buckets
         # per-object upper bound on covered keys (never lowered by
@@ -987,19 +979,6 @@ class IntervalLRUState:
         self.miss_bytes = 0
         self.evictions = 0
         self.inserted_bytes = 0
-        # phase-B logs: (req_pos, key_lo, key_hi) int triples
-        self.miss_log: list[tuple[int, int, int]] = []
-        self.insert_log: list[tuple[int, int, int]] = []
-        self.evict_log: list[tuple[int, int, int]] = []
-        # (req_pos, evicted ranges, remaining live ranges of that request's
-        # WHOLE insert group) — one entry per eviction that consumed part
-        # of a request's inserts while other chunks of the same request
-        # survived; ``remaining is None`` marks a mid-insert self-eviction
-        # (always order-sensitive unless the request had no peer chunks)
-        self.split_log: list[tuple[int, list, "list | None"]] = []
-        # insert records per request (log mode only): the audit needs the
-        # whole group because the reference orders *records* peer-first too
-        self._req_records: dict[int, list] = {}
 
     # -- introspection -------------------------------------------------------
 
@@ -1159,7 +1138,7 @@ class IntervalLRUState:
 
     # -- eviction ------------------------------------------------------------
 
-    def _evict_until(self, size: int, t_now: int) -> None:
+    def _evict_until(self, size: int) -> None:
         """Evict chunks in exact LRU order until ``used + size`` fits.
         Mirrors the reference's one-chunk-at-a-time loop arithmetically:
         per victim size run, evict ``ceil(shortfall / chunk_size)`` chunks."""
@@ -1171,10 +1150,9 @@ class IntervalLRUState:
             rid = rec[0]                # the reference's evict-from-empty
             if rid not in live:
                 continue                # fully stale record: O(1) skip
-            _, obj, lo, hi, src = rec
+            _, obj, lo, hi = rec
             self._zmemo.pop(obj, None)
             segs = self._valid_segs(rid, obj, lo, hi)
-            evicted: list[tuple[int, int]] = []
             stopped_at = None
             zmap = self._sizes[obj]
             zs, ze, zz = zmap
@@ -1198,9 +1176,6 @@ class IntervalLRUState:
                     n_ev = stop - s
                     self.n_live -= n_ev
                     self.evictions += n_ev
-                    evicted.append((s, stop))
-                    if self._log:
-                        self.evict_log.append((t_now, s, stop))
                     self._splice_r(rmap, s, stop, None)
                     self._splice_z(zmap, s, stop, None)
                 if stop < e:
@@ -1209,33 +1184,7 @@ class IntervalLRUState:
             if stopped_at is not None:
                 # record only partially consumed: re-queue the remainder at
                 # the head (it is still the oldest recency)
-                fifo.appendleft((rid, obj, stopped_at, hi, src))
-            if src >= 0 and evicted and self._log:
-                # part of request ``src``'s inserts was evicted: whether
-                # these exact chunks are the reference's victims depends on
-                # the peer-vs-origin insert order across the request's
-                # WHOLE insert group (the reference queues peer-fetched
-                # records before origin records) — log the event for the
-                # engine's phase-B exactness audit, unless the pop killed
-                # the group's last live chunks (then the evicted *set* is
-                # order-independent)
-                if src == t_now:
-                    # eviction reached the request currently being inserted:
-                    # phase A's live set itself depends on the insert order
-                    self.split_log.append((src, evicted, None))
-                else:
-                    remaining: list = []
-                    if stopped_at is not None:
-                        remaining += self._valid_segs(rid, obj, stopped_at,
-                                                      hi)
-                    for rid2, obj2, lo2, hi2 in self._req_records.get(
-                            src, ()):
-                        if rid2 != rid:
-                            remaining += self._valid_segs(rid2, obj2, lo2,
-                                                          hi2)
-                    if remaining:
-                        self.split_log.append((src, evicted, remaining))
-            if stopped_at is not None:
+                fifo.appendleft((rid, obj, stopped_at, hi))
                 return
 
     # -- bulk block APIs (fused block-over-intervals replay) -----------------
@@ -1316,7 +1265,7 @@ class IntervalLRUState:
             if total >= target:
                 exhausted = False
                 break
-            rid, obj, lo, hi, _src = rec
+            rid, obj, lo, hi = rec
             if rid not in self._rid_live:
                 continue
             for s, e in self._valid_segs(rid, obj, lo, hi):
@@ -1365,25 +1314,21 @@ class IntervalLRUState:
                      r_grp: "list | None" = None) -> None:
         """Bulk-commit one fused replay block.
 
-        ``size_recs``: ``(obj, lo, hi, req_pos, size)`` insert runs merged
+        ``size_recs``: ``(obj, lo, hi, size)`` insert runs merged
         per *inserting* (first-toucher) request, in trace order — they
         carry presence bookkeeping: size map, ``used``/``n_live``/
-        ``inserted_bytes``, ``obj_hi`` and (in log mode) the miss/insert
-        logs plus the request's audit group.
+        ``inserted_bytes`` and ``obj_hi``.
 
-        ``recency_recs``: ``(obj, lo, hi, src)`` runs merged per final
-        stamp, ordered by (last-touching request, hit/peer/origin phase,
-        ascending key) — exactly the reference's per-chunk final recency
-        order, so appending them as FIFO records reproduces its LRU order.
-        ``src`` is the last toucher's position for its own single-touch
-        inserts and ``-1`` for re-touches, mirroring ``lookup_touch`` /
-        ``insert_runs``.  Equivalent to replaying the block's requests one
-        by one because only each chunk's *final* stamp is observable: the
-        caller truncates blocks so no in-block key is evicted mid-block,
-        and intermediate stamps of multiply-touched chunks are therefore
-        never consulted.
+        ``recency_recs``: ``(obj, lo, hi)`` runs merged per final stamp,
+        ordered by (last-touching request, hit/peer/origin phase, ascending
+        key) — exactly the reference's per-chunk final recency order, so
+        appending them as FIFO records reproduces its LRU order.
+        Equivalent to replaying the block's requests one by one because
+        only each chunk's *final* stamp is observable: the caller truncates
+        blocks so no in-block key is evicted mid-block, and intermediate
+        stamps of multiply-touched chunks are therefore never consulted.
 
-        ``r_grp`` (non-log mode): group ids, parallel to
+        ``r_grp`` (optional): group ids, parallel to
         ``recency_recs``, contiguous and non-decreasing — records in one
         group (same DTN-object group, consecutive final stamps, ascending
         disjoint key runs) are fused under ONE record id and ONE FIFO
@@ -1393,18 +1338,17 @@ class IntervalLRUState:
         occupy the same relative FIFO positions, and (c) keys in the gaps
         between a group's runs carry other rids and are filtered out by
         rid validity wherever the record is consulted."""
-        log = self._log
         oh = self.obj_hi
         objs = self._objs
         sizes = self._sizes
         zmemo = self._zmemo
         p = self._plan
         if p is not None:
-            for obj, a, b, _src in recency_recs:
+            for obj, a, b in recency_recs:
                 if p.overlaps(a, b):
                     self._plan = None   # re-touch of a planned victim
                     break
-        for obj, a, b, src, size in size_recs:
+        for obj, a, b, size in size_recs:
             zmemo.pop(obj, None)
             zmap = sizes.get(obj)
             if zmap is None:
@@ -1417,19 +1361,13 @@ class IntervalLRUState:
             self.inserted_bytes += nm * size
             if b > oh.get(obj, 0):
                 oh[obj] = b
-            if log:
-                self.miss_log.append((src, a, b))
-                self.insert_log.append((src, a, b))
         fifo = self._fifo
         if r_grp is None:
-            for obj, a, b, src in recency_recs:
+            for obj, a, b in recency_recs:
                 rid = self._next_rid
                 self._next_rid = rid + 1
-                fifo.append((rid, obj, a, b, src))
+                fifo.append((rid, obj, a, b))
                 self._splice_r(objs[obj], a, b, [[a], [b], [rid]])
-                if log and src >= 0:
-                    self._req_records.setdefault(src, []).append(
-                        (rid, obj, a, b))
             return
         k = 0
         n = len(recency_recs)
@@ -1440,16 +1378,11 @@ class IntervalLRUState:
                 j += 1
             rid = self._next_rid
             self._next_rid = rid + 1
-            obj, a0, b0, src0 = recency_recs[k]
-            hi_last = recency_recs[j - 1][2]
-            src = src0 if j == k + 1 else -1
-            fifo.append((rid, obj, a0, hi_last, src))
+            obj, a0 = recency_recs[k][:2]
+            fifo.append((rid, obj, a0, recency_recs[j - 1][2]))
             m = objs[obj]
-            for _o, a, b, _s in recency_recs[k:j]:
+            for _o, a, b in recency_recs[k:j]:
                 self._splice_r(m, a, b, [[a], [b], [rid]])
-            if log and src >= 0:
-                self._req_records.setdefault(src, []).append(
-                    (rid, obj, a0, b0))
             k = j
 
     # -- serving -------------------------------------------------------------
@@ -1491,7 +1424,7 @@ class IntervalLRUState:
                     return nh, ()
                 rid = self._next_rid
                 self._next_rid = rid + 1
-                fifo.append((rid, obj, lo, hi, -1))
+                fifo.append((rid, obj, lo, hi))
                 c = live[old] - nh
                 if c:
                     live[old] = c
@@ -1502,7 +1435,7 @@ class IntervalLRUState:
                 return nh, ()
             rid = self._next_rid
             self._next_rid = rid + 1
-            fifo.append((rid, obj, lo, hi, -1))
+            fifo.append((rid, obj, lo, hi))
             c = live[old] - nh
             if c:
                 live[old] = c
@@ -1552,7 +1485,7 @@ class IntervalLRUState:
             for a, b in hit_runs:
                 rid = self._next_rid
                 self._next_rid = rid + 1
-                fifo.append((rid, obj, a, b, -1))
+                fifo.append((rid, obj, a, b))
                 h_s.append(a); h_e.append(b); h_r.append(rid)
             self._splice_r(m, lo, hi, [h_s, h_e, h_r])
         return nh, miss_runs
@@ -1580,8 +1513,7 @@ class IntervalLRUState:
             i += 1
         return out
 
-    def insert_runs(self, obj: int, runs: list, size: int,
-                    req_pos: int) -> None:
+    def insert_runs(self, obj: int, runs: list, size: int) -> None:
         """Insert absent chunk runs (ascending) with reference ``insert``
         semantics: oversized chunks are skipped silently, eviction happens
         chunk by chunk ahead of each insertion, one FIFO record per
@@ -1598,52 +1530,39 @@ class IntervalLRUState:
             fifo = self._fifo
             m = self._objs[obj]
             zmap = self._sizes[obj]
-            log = self._log
             for a, b in runs:
                 rid = self._next_rid
                 self._next_rid = rid + 1
-                fifo.append((rid, obj, a, b, req_pos))
-                if log:
-                    self.insert_log.append((req_pos, a, b))
-                    self._req_records.setdefault(req_pos, []).append(
-                        (rid, obj, a, b))
+                fifo.append((rid, obj, a, b))
                 self._splice_r(m, a, b, [[a], [b], [rid]])
                 self._splice_z(zmap, a, b, ([a], [b], [size]))
             self.used += nm * size
             self.n_live += nm
             self.inserted_bytes += nm * size
             return
-        self._insert_with_evict(obj, runs, size, req_pos)
+        self._insert_with_evict(obj, runs, size)
 
-    def serve(self, req_pos: int, obj: int, lo: int, hi: int,
-              size: int) -> int:
-        """Serve one request assuming every miss is inserted in ascending
-        chunk order (the sharded driver's optimistic phase A — exact unless
-        an eviction later splits one of this request's insert records AND
-        the true peer/origin partition disagrees; the driver audits that).
-        Returns the hit count."""
+    def serve(self, obj: int, lo: int, hi: int, size: int) -> int:
+        """Serve one request with every miss inserted in ascending chunk
+        order — the reference's static serve when no chunk comes from a
+        peer.  Returns the hit count."""
         nh, miss_runs = self.lookup_touch(obj, lo, hi, size)
         if miss_runs:
-            if self._log:
-                ml = self.miss_log
-                for a, b in miss_runs:
-                    ml.append((req_pos, a, b))
-            self.insert_runs(obj, miss_runs, size, req_pos)
+            self.insert_runs(obj, miss_runs, size)
         return nh
 
-    def _insert_with_evict(self, obj: int, miss_runs: list, size: int,
-                           req_pos: int) -> None:
+    def _insert_with_evict(self, obj: int, miss_runs: list,
+                           size: int) -> None:
         """Insert miss runs chunk-group-wise, evicting ahead of each group —
         the reference's per-chunk evict-then-insert loop in range form.
         Runs after the hit touches so the request's own hits are already
         protected by fresh rids."""
         fifo = self._fifo
-        log = self._log
         for a, b in miss_runs:
             j = a
             while j < b:
                 if self.used + size > self.capacity:
-                    self._evict_until(size, req_pos)
+                    self._evict_until(size)
                 cnt = min(b - j, (self.capacity - self.used) // size)
                 rid = self._next_rid
                 self._next_rid = rid + 1
@@ -1651,11 +1570,7 @@ class IntervalLRUState:
                                [[j], [j + cnt], [rid]])
                 self._splice_z(self._sizes[obj], j, j + cnt,
                                ([j], [j + cnt], [size]))
-                fifo.append((rid, obj, j, j + cnt, req_pos))
-                if log:
-                    self.insert_log.append((req_pos, j, j + cnt))
-                    self._req_records.setdefault(req_pos, []).append(
-                        (rid, obj, j, j + cnt))
+                fifo.append((rid, obj, j, j + cnt))
                 self.used += cnt * size
                 self.n_live += cnt
                 self.inserted_bytes += cnt * size

@@ -33,7 +33,7 @@ class Prefetcher(Protocol):
 
 @dataclasses.dataclass(frozen=True)
 class PlannedPrediction:
-    """Whole-trace prediction plan: for request ``i``, the non-stream ops to
+    """One window's prediction plan: for request ``i``, the non-stream ops to
     schedule (``ops[i]``) and the streaming subscriptions to register
     (``subscriptions[i]``, args of :meth:`StreamingEngine.subscribe`) — the
     exact side effects ``observe`` would have produced at that request."""
@@ -85,28 +85,17 @@ class HPMAdapter:
                 out.append(op)
         return out
 
-    def plan(self, requests: Sequence[Request]) -> PlannedPrediction:
-        """Batch mode: pre-compute the whole-trace prediction plan through
-        the two-phase planner (vmapped ARIMA bank, memoized rules).  Emits
-        exactly what per-request :meth:`observe` calls would — ops op-for-op
-        and subscriptions at the same request positions — without mutating
-        the online model's state."""
+    def planner(self) -> "HPMWindowPlanner":
+        """Batch mode: a stateful two-phase planner (vmapped ARIMA bank,
+        memoized rules) whose ``plan_window`` calls may split the trace at
+        arbitrary points (``BatchedHPMPlanner`` carries per-user
+        classification state across windows; any split emits the identical
+        op stream).  It emits exactly what per-request :meth:`observe` calls
+        would — ops op-for-op and subscriptions at the same request
+        positions — without mutating the online model's state."""
         if self.model.users:
             # the planner replays classification from scratch; planning on
             # top of observe()-accumulated state would silently diverge
-            raise RuntimeError(
-                "plan() requires an unobserved model: this adapter already "
-                "processed requests via observe()")
-        per_req = BatchedHPMPlanner(self.model).plan(requests)
-        return _route_planned_ops(requests, per_req)
-
-    def planner(self) -> "HPMWindowPlanner":
-        """Window mode: a stateful planner whose ``plan_window`` calls may
-        split the trace at arbitrary points (``BatchedHPMPlanner`` carries
-        per-user classification state across windows; any split emits the
-        identical op stream).  Same fresh-model precondition as
-        :meth:`plan`."""
-        if self.model.users:
             raise RuntimeError(
                 "planner() requires an unobserved model: this adapter "
                 "already processed requests via observe()")
@@ -118,7 +107,7 @@ def _route_planned_ops(requests: Sequence[Request],
                        ) -> PlannedPrediction:
     """Route a planner's per-request op lists the way ``observe`` does:
     stream ops become subscriptions, everything else is scheduled as a
-    prefetch.  ONE definition for whole-trace and windowed planning."""
+    prefetch."""
     ops: list[Sequence[PrefetchOp]] = []
     subs: list[Sequence[tuple]] = []
     empty: tuple = ()
@@ -233,22 +222,6 @@ class PeerFetchRange(typing.NamedTuple):
     src: int
     key_lo: int
     key_hi: int
-
-
-def coalesce_peer_fetches(req_pos: np.ndarray, keys: np.ndarray,
-                          src: np.ndarray, dtn: int) -> list[PeerFetchRange]:
-    """Group accepted per-chunk peer decisions into contiguous
-    :class:`PeerFetchRange` transfers (same request, same source, adjacent
-    chunk keys).  The interval replay engine uses this to expose its phase-B
-    peer plan as ranges instead of chunk lists."""
-    out: list[PeerFetchRange] = []
-    for r, k, s in zip(req_pos.tolist(), keys.tolist(), src.tolist()):
-        if out and out[-1].req_pos == r and out[-1].src == s \
-                and out[-1].key_hi == k:
-            out[-1] = out[-1]._replace(key_hi=k + 1)
-        else:
-            out.append(PeerFetchRange(r, dtn, s, k, k + 1))
-    return out
 
 
 def select_peer_sources_ranges(bw_col: np.ndarray, holders: np.ndarray

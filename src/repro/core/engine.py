@@ -7,7 +7,7 @@ That caps replay at a few thousand requests/second — far from the paper's
 
 This module replays the same discrete-event semantics on array state:
 
-- chunk ranges for the *whole* trace are precomputed in bulk
+- chunk ranges of each window are precomputed in bulk
   (:func:`repro.core.cache.chunk_bounds_bulk`);
 - each DTN cache is an :class:`repro.core.cache.IntCacheState` — presence,
   recency and sizes in flat NumPy arrays keyed by dense chunk ids
@@ -27,10 +27,14 @@ covered by ``tests/test_engine_equivalence.py``): identical integer counters
 (origin requests, hits/misses/evictions, prefetch issue/use, byte splits)
 and float aggregates equal to within summation-order rounding.  The same
 prefetcher / streaming / placement model classes are used by both engines;
-prefetchers that support batch planning (hpm) are pre-planned through the
-two-phase planner here (``SimConfig.batched_prediction``), whose op stream
-is bitwise identical to the online ``observe`` loop the reference replays
-(``tests/test_hpm_equivalence.py``).
+prefetchers that expose a window planner (hpm) are planned through the
+two-phase planner here, whose op stream is bitwise identical to the online
+``observe`` loop the reference replays (``tests/test_hpm_equivalence.py``);
+prefetchers without one (md1, md2) are observed online.
+
+A materialized trace is replayed as a single window of a
+:class:`repro.core.trace.StreamingRequestSource`, so each engine has one
+replay body for lists and streams alike.
 """
 from __future__ import annotations
 
@@ -39,8 +43,6 @@ import collections.abc
 import heapq
 import itertools
 import math
-import multiprocessing
-import os
 from typing import Sequence
 
 import numpy as np
@@ -48,8 +50,7 @@ import numpy as np
 from repro.core.cache import (CacheStats, IntervalLRUState, chunk_bytes,
                               chunk_bounds_bulk, make_int_cache_state)
 from repro.core.interval_store import FlatIntervalState
-from repro.core.delivery import (PeerFetchRange, coalesce_peer_fetches,
-                                 coalesce_peer_ranges,
+from repro.core.delivery import (PeerFetchRange, coalesce_peer_ranges,
                                  select_peer_sources,
                                  select_peer_sources_ranges)
 from repro.core import telemetry
@@ -272,43 +273,14 @@ class VectorVDCSimulator:
     # -- main entry ----------------------------------------------------------
 
     def run(self, requests: Sequence[Request], name: str = "") -> SimResult:
+        """Replay a trace.  A list is replayed as one window of a
+        :class:`StreamingRequestSource` and keeps its per-request
+        ``outcomes``; a source is replayed window by window with its
+        outcomes folded into ``aggregate``."""
         if isinstance(requests, StreamingRequestSource):
             return self._run_stream(requests, name)
-        with telemetry.span("vdc.engine.prep"):
-            arr = requests_to_arrays(requests)
-            A = self._prep_window(arr)
-        n_req = len(arr)
-        stream_engine = getattr(self.pf, "streaming", None)
-        static = (self.placement is None and stream_engine is None
-                  and getattr(self.pf, "static", False))
-        if static:
-            self._run_static(A)
-        else:
-            self._run_dynamic(A, stream_engine)
-
-        outcomes = _LazyOutcomes((
-            A["now"], arr.user_id, self._o_bytes, self._o_lat, self._o_tra,
-            self._o_loc, self._o_pref, self._o_peer, self._o_org,
-            self._o_pt))
-        if self.use_cache:
-            stats = {d: c.to_cache_stats() for d, c in self.caches.items()}
-        else:
-            stats = {d: CacheStats() for d in range(1, self.n_dtn)}
-        return SimResult(
-            name=name or self.pf.name,
-            outcomes=outcomes,
-            origin_requests=int((self._o_org > 0).sum()),
-            total_requests=n_req,
-            prefetch_issued_chunks=self._pref_issued,
-            prefetch_used_chunks=self._pref_used,
-            cache_stats=stats,
-            stream_pushes=stream_engine.pushes_emitted if stream_engine else 0,
-            evict_plan_calls=self._ctr["plan"],
-            block_truncations=self._ctr["trunc"],
-            degenerate_serves=self._ctr["degen"],
-            block_phases=self._ctr["phases"],
-            inblock_victims=self._ctr["invict"],
-        )
+        return self._run_stream(StreamingRequestSource.from_requests(
+            requests, window=max(1, len(requests))), name, keep_outcomes=True)
 
     def _prep_window(self, arr, hint: tuple[int, int] | None = None,
                      grow: bool = False) -> dict:
@@ -371,24 +343,27 @@ class VectorVDCSimulator:
 
     # -- streaming entry (windowed replay over a StreamingRequestSource) -----
 
-    def _run_stream(self, source: StreamingRequestSource,
-                    name: str = "") -> SimResult:
+    def _run_stream(self, source: StreamingRequestSource, name: str = "",
+                    keep_outcomes: bool = False) -> SimResult:
         """Windowed replay: identical per-request arithmetic and event order
-        to :meth:`run` on the materialized trace, with only one window of
-        requests resident at a time.
+        for any window split, with only one window of requests resident at
+        a time.
 
         Exactness: static block replay never depends on block extent (the
         truncation invariants hold for any boundary placement), so forcing
         block boundaries at window edges changes no counter.  The dynamic
         path keeps the event heap and its creation counter alive across
         windows; requests are never heaped, and the merged loop's strict
-        ``event_ts < request_ts`` pop condition reproduces the materialized
-        event order for any window split.  Batched prediction goes through
-        the prefetcher's stateful window planner, whose op stream is
+        ``event_ts < request_ts`` pop condition gives the same event order
+        for any window split.  A prefetcher that exposes a window planner
+        (hpm) is planned through it, window by window; its op stream is
         window-split invariant (``tests/test_hpm_equivalence.py``).  Outcome
         columns are folded into an :class:`OutcomeAggregate` per window
         instead of a ``len(trace)`` outcome list, so peak memory is bounded
-        by the window size plus the dense key space."""
+        by the window size plus the dense key space.  With
+        ``keep_outcomes`` (a materialized trace, replayed as one window)
+        that window's columns become the per-request ``outcomes``
+        instead."""
         cfg = self.cfg
         stream_engine = getattr(self.pf, "streaming", None)
         static = (self.placement is None and stream_engine is None
@@ -398,13 +373,14 @@ class VectorVDCSimulator:
             cs = cfg.chunk_seconds
             hint = (int(math.floor(source.tr_bounds[0] / cs)),
                     int(math.ceil(source.tr_bounds[1] / cs)) + 1)
-        agg = OutcomeAggregate()
+        agg = None if keep_outcomes else OutcomeAggregate()
+        outcomes: Sequence[RequestOutcome] = []
         origin_requests = 0
         n_total = 0
         heap: list = []
         counter = itertools.count()   # orders dynamic events among themselves
         planner = None
-        if not static and cfg.batched_prediction:
+        if not static:
             planner_fn = getattr(self.pf, "planner", None)
             if planner_fn is not None:
                 planner = planner_fn()
@@ -421,16 +397,21 @@ class VectorVDCSimulator:
                     self._run_dyn_window(A, stream_engine, heap, counter,
                                          planner)
                 with telemetry.span("vdc.engine.fold"):
-                    agg.add_columns(self._o_bytes, self._o_lat, self._o_tra,
-                                    self._o_loc, self._o_pref, self._o_peer,
-                                    self._o_org, self._o_pt)
+                    if keep_outcomes:
+                        outcomes = self._outcomes(A)
+                    else:
+                        agg.add_columns(self._o_bytes, self._o_lat,
+                                        self._o_tra, self._o_loc,
+                                        self._o_pref, self._o_peer,
+                                        self._o_org, self._o_pt)
                     origin_requests += int((self._o_org > 0).sum())
             n_total += len(arr)
         if first:
             # empty source: allocate the (empty) address space so cache
-            # stats report per-DTN zeros exactly like an empty materialized
-            # run
-            self._prep_window(requests_to_arrays([]), hint=hint)
+            # stats report per-DTN zeros
+            A = self._prep_window(requests_to_arrays([]), hint=hint)
+            if keep_outcomes:
+                outcomes = self._outcomes(A)
         if not static:
             self._dyn_drain(heap, stream_engine)
         if self.use_cache:
@@ -439,7 +420,7 @@ class VectorVDCSimulator:
             stats = {d: CacheStats() for d in range(1, self.n_dtn)}
         return SimResult(
             name=name or self.pf.name,
-            outcomes=[],
+            outcomes=outcomes,
             origin_requests=origin_requests,
             total_requests=n_total,
             prefetch_issued_chunks=self._pref_issued,
@@ -453,6 +434,13 @@ class VectorVDCSimulator:
             block_phases=self._ctr["phases"],
             inblock_victims=self._ctr["invict"],
         )
+
+    def _outcomes(self, A: dict) -> _LazyOutcomes:
+        """One window's outcome columns as per-request outcomes."""
+        return _LazyOutcomes((
+            A["now"], A["arr"].user_id, self._o_bytes, self._o_lat,
+            self._o_tra, self._o_loc, self._o_pref, self._o_peer,
+            self._o_org, self._o_pt))
 
     # -- static fast path (no dynamic events) --------------------------------
 
@@ -882,26 +870,6 @@ class VectorVDCSimulator:
             o_org[idx] = ob
 
     # -- dynamic path (prefetch / streaming / placement events) --------------
-
-    def _run_dynamic(self, A: dict, stream_engine) -> None:
-        # batched prediction: prefetchers that expose a plan (hpm) have
-        # their whole op stream pre-computed in two phases — classification
-        # over per-user arrays, then vmapped-ARIMA-bank flush — instead of
-        # per-request observe() calls inside the event loop.  The plan is
-        # op-for-op identical to the online stream (the planner contract).
-        # Only this mode materializes all scaled requests at once; the
-        # online path keeps constructing them per event.
-        plan = None
-        reqs = None
-        plan_fn = getattr(self.pf, "plan", None)
-        if plan_fn is not None and self.cfg.batched_prediction:
-            with telemetry.span("vdc.engine.prep"):
-                reqs = self._scaled_requests(A)
-            plan = plan_fn(reqs)
-        heap: list = []
-        counter = itertools.count(len(A["arr"]))   # requests own 0..n-1
-        self._dyn_loop(A, stream_engine, heap, counter, plan, reqs)
-        self._dyn_drain(heap, stream_engine)
 
     def _run_dyn_window(self, A: dict, stream_engine, heap: list, counter,
                         planner) -> None:
@@ -1334,107 +1302,21 @@ class VectorVDCSimulator:
 
 
 # ---------------------------------------------------------------------------
-# Interval-algebra replay + sharded multi-DTN driver (third engine mode)
+# Interval-algebra replay (third engine mode)
 # ---------------------------------------------------------------------------
 #
 # The vector engine above still spends O(total chunk positions) on the
 # serving path.  The interval engine replays static strategies (no dynamic
-# events) on :class:`repro.core.cache.IntervalLRUState` — presence, sizes
-# and LRU recency as sorted disjoint [start, end) chunk-id intervals — in
-# three phases:
+# events) on interval cache states — presence, sizes and LRU recency as
+# sorted disjoint [start, end) chunk-id intervals — in two steps:
 #
-#   A. per-DTN interval sweeps.  In a static replay every missed chunk is
-#      inserted into the local cache regardless of where it was fetched
-#      from, so each DTN's entire cache trajectory (hits, misses, LRU
-#      order, evictions) depends only on its own request subsequence.  The
-#      sweeps are therefore embarrassingly parallel, and the sharded driver
-#      forks worker processes that each replay a subset of the DTNs.
-#   B. peer-fetch resolution.  Phase A logs every cache's presence changes
-#      as (trace position, key range) events; misses are resolved against
-#      the other caches' *presence timelines* (per-chunk [t_in, t_out)
-#      intervals over trace positions) with bulk searchsorted — the only
-#      point where DTNs synchronize, exactly as the paper's §IV-D
-#      resolution order prescribes.
-#   C. origin-queue replay.  Requests with chunks left over after peer
+#   1. serving, with peer fetches resolved inline against the other caches'
+#      coverage: the fused block-over-intervals replay below (coarse
+#      chunking) or the per-request interval sweep (:func:`_sweep_serve`,
+#      fine chunking);
+#   2. origin-queue replay.  Requests with chunks left over after peer
 #      resolution walk the (inherently sequential, but tiny) origin task
 #      queue in trace order — identical float arithmetic to the reference.
-#
-# Exactness audit: the one place where phase separation could diverge from
-# the reference is the LRU insert order *inside* a single request — the
-# reference inserts peer-fetched chunks before origin-fetched ones, phase A
-# assumes ascending chunk order.  That order is only observable when an
-# eviction later consumes part of that request's insert record (a "split
-# event", logged by IntervalLRUState).  Phase B re-checks every split event
-# against the true peer partition; in the (rare) case a split is actually
-# order-sensitive the engine discards the interval replay and falls back to
-# the vector engine, which interleaves peer resolution exactly.  Counter
-# equivalence is therefore unconditional (tests/test_engine_equivalence.py).
-
-
-class _IntervalOrderAmbiguity(Exception):
-    """Raised when a logged eviction split event is sensitive to the
-    peer-vs-origin insert order (phase A's ascending-key assumption is not
-    provably exact) — the caller falls back to the vector engine."""
-
-
-def _ranges_to_chunks(t: np.ndarray, a: np.ndarray, b: np.ndarray
-                      ) -> tuple[np.ndarray, np.ndarray]:
-    """Expand (tag, key_lo, key_hi) ranges into per-chunk (keys, tags)."""
-    cnt = b - a
-    tot = int(cnt.sum())
-    if tot == 0:
-        z = np.empty(0, np.int64)
-        return z, z
-    starts = np.cumsum(cnt) - cnt
-    keys = np.arange(tot, dtype=np.int64) + np.repeat(a - starts, cnt)
-    return keys, np.repeat(t, cnt)
-
-
-class PresenceTimeline:
-    """One DTN cache's presence history as per-chunk ``[t_in, t_out)``
-    intervals over global trace positions, built from phase-A insert/evict
-    range logs and queryable in bulk.
-
-    Queries ask "did this cache hold chunk ``k`` when the (other-DTN)
-    request at trace position ``q`` was served?".  Positions of different
-    DTNs never collide, so strict interval membership ``t_in < q < t_out``
-    needs no tie-breaking; an insert and an evict at the same position
-    (a request whose own later inserts evicted its earlier ones) form an
-    empty interval, correctly invisible to peers.
-    """
-
-    __slots__ = ("_comb", "_kin", "_tout", "_m")
-
-    def __init__(self, ins: np.ndarray, ev: np.ndarray, horizon: int):
-        m = horizon + 1                      # strict upper bound on positions
-        ki, ti = _ranges_to_chunks(ins[:, 0], ins[:, 1], ins[:, 2])
-        ke, te = _ranges_to_chunks(ev[:, 0], ev[:, 1], ev[:, 2])
-        kk = np.concatenate([ki, ke])
-        tt = np.concatenate([ti, te])
-        typ = np.concatenate([np.zeros(len(ki), np.int64),
-                              np.ones(len(ke), np.int64)])
-        order = np.argsort(kk * (2 * m) + tt * 2 + typ)
-        sk, st, sty = kk[order], tt[order], typ[order]
-        ins_mask = sty == 0
-        kin, tin = sk[ins_mask], st[ins_mask]
-        pos = np.nonzero(ins_mask)[0]
-        nxt = np.minimum(pos + 1, max(0, len(sk) - 1))
-        tout = np.full(len(pos), m, np.int64)
-        if len(sk):
-            closed = (pos + 1 < len(sk)) & (sk[nxt] == kin) & (sty[nxt] == 1)
-            tout[closed] = st[nxt[closed]]
-        self._comb = kin * m + tin
-        self._kin = kin
-        self._tout = tout
-        self._m = m
-
-    def query(self, keys: np.ndarray, q: np.ndarray) -> np.ndarray:
-        """Bool mask: chunk ``keys[i]`` present at trace position ``q[i]``."""
-        if not len(self._comb):
-            return np.zeros(len(keys), np.bool_)
-        idx = np.searchsorted(self._comb, keys * self._m + q) - 1
-        idc = np.maximum(idx, 0)
-        return (idx >= 0) & (self._kin[idc] == keys) & (self._tout[idc] > q)
 
 
 # --------------------------------------------------------------------------
@@ -1495,24 +1377,16 @@ _FUSED_MAX_INCIDENCE = 1 << 21
 _FUSED_PHASE_MAX = 64
 
 
-def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
+def _fused_block_replay(states: dict, bw, enable_peer: bool,
                         pos_a: np.ndarray, dtn_a: np.ndarray,
                         obj_a: np.ndarray, lo_a: np.ndarray,
                         hi_a: np.ndarray, pc_a: np.ndarray,
                         ctr: dict | None = None,
                         blk_state: dict | None = None):
-    """Fused replay of one request sequence (trace order) over per-DTN
-    :class:`IntervalLRUState` caches.
-
-    Two callers:
-
-    - the global fused path (``log=False``): all DTNs interleaved, peer
-      ranges resolved inline against the block snapshots (exact, no
-      audit); returns per-request ``(nh, peer_chunks, peer_dt,
-      still_chunks, peer_ranges)``;
-    - the sharded driver's per-DTN phase A (``log=True``): one DTN's
-      subsequence, no peer logic, miss/insert/evict/split logs recorded on
-      the state for phase B; returns ``None``.
+    """Fused replay of one request sequence (trace order, all DTNs
+    interleaved) over per-DTN interval cache states, peer ranges resolved
+    inline against the block snapshots.  Returns per-request ``(nh,
+    peer_chunks, peer_dt, still_chunks)`` and the accepted peer ranges.
 
     Blocks under eviction pressure are replayed in PHASES: the fitting
     prefix is committed, victims are evicted at the phase boundary, and
@@ -1531,21 +1405,20 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
     # homogeneous state bank: flat states take the batched array APIs
     # (plan_evict_clean on key-run arrays, commit_block_arrays)
     flat = getattr(next(iter(states.values())), "flat", False)
-    if not log:
-        nh_loc = np.zeros(n, np.int64)
-        acc_loc = np.zeros(n, np.int64)
-        pdt_loc = np.zeros(n, np.float64)
-        still_loc = np.zeros(n, np.int64)
-        peer_ranges: list = []
-        # peer candidates per DTN, best-first, for the scalar fallback
-        # (same pruning + greedy order as the sequential sweep)
-        cands: dict[int, list] = {}
-        for d in active:
-            ob = float(bw[0, d])
-            cl = [(float(bw[d2, d]), d2) for d2 in active
-                  if d2 != d and float(bw[d2, d]) > ob]
-            cl.sort(key=lambda t: (-t[0], t[1]))
-            cands[d] = cl
+    nh_loc = np.zeros(n, np.int64)
+    acc_loc = np.zeros(n, np.int64)
+    pdt_loc = np.zeros(n, np.float64)
+    still_loc = np.zeros(n, np.int64)
+    peer_ranges: list = []
+    # peer candidates per DTN, best-first, for the scalar fallback
+    # (same pruning + greedy order as the sequential sweep)
+    cands: dict[int, list] = {}
+    for d in active:
+        ob = float(bw[0, d])
+        cl = [(float(bw[d2, d]), d2) for d2 in active
+              if d2 != d and float(bw[d2, d]) > ob]
+        cl.sort(key=lambda t: (-t[0], t[1]))
+        cands[d] = cl
 
     def serve_scalar(r: int) -> None:
         ctr["degen"] += 1
@@ -1553,9 +1426,6 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
         lo = int(lo_a[r]); hi = int(hi_a[r])
         pc = int(pc_a[r]); ridx = int(pos_a[r])
         st = states[d]
-        if log:
-            st.serve(ridx, o, lo, hi, pc)
-            return
         nh, miss = st.lookup_touch(o, lo, hi, pc)
         nh_loc[r] = nh
         if not miss:
@@ -1585,13 +1455,13 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
                 unassigned = rem
             if acc_runs:
                 acc_runs.sort()
-                st.insert_runs(o, acc_runs, pc, ridx)
+                st.insert_runs(o, acc_runs, pc)
             still = unassigned
         else:
             still = miss
         if still:
             still_loc[r] = sum(b_ - a for a, b_ in still)
-            st.insert_runs(o, still, pc, ridx)
+            st.insert_runs(o, still, pc)
         acc_loc[r] = n_acc
         pdt_loc[r] = peer_dt
 
@@ -1769,22 +1639,13 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
                 base = int(cum_d[p0 - 1]) if p0 else 0
                 st = states[d]
                 ev0 = st.evictions
-                if log:
-                    # per-request calls: the evict/split logs need each
-                    # eviction stamped with its triggering request
-                    for r_loc in (p0
-                                  + bins[d][p0:b1].nonzero()[0]).tolist():
-                        cv = int(cum_d[r_loc]) - base
-                        if st.used + cv > st.capacity:
-                            st._evict_until(cv, int(pos_a[i + r_loc]))
-                else:
-                    # one call with the phase's final cumulative need: LRU
-                    # prefix consumption is monotone, so evicting for the
-                    # per-request cumulative values in sequence lands on
-                    # the same final prefix (t_now unread outside log mode)
-                    cv = int(cum_d[b1 - 1]) - base
-                    if cv > 0 and st.used + cv > st.capacity:
-                        st._evict_until(cv, int(pos_a[i + b1 - 1]))
+                # one call with the phase's final cumulative need: LRU
+                # prefix consumption is monotone, so evicting for the
+                # per-request cumulative values in sequence lands on the
+                # same final prefix
+                cv = int(cum_d[b1 - 1]) - base
+                if cv > 0 and st.used + cv > st.capacity:
+                    st._evict_until(cv)
                 if inblock:
                     ctr["invict"] += st.evictions - ev0
 
@@ -1804,7 +1665,7 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
         n_ins = len(ins_idx)
         acc2 = None
         acc = np.zeros(n_ins, bool)
-        if not log and enable_peer and n_ins:
+        if enable_peer and n_ins:
             holders = np.zeros((n_dtn, n_ins), bool)
             for d2 in active:
                 # a DTN holds a cell at serve time iff it was present at
@@ -1834,33 +1695,24 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
                 iuc = iuc[o2]; ifi = ifi[o2]
                 brk = np.empty(len(iuc), bool)
                 brk[0] = True
-                if log:
-                    # log mode: miss/insert logs and audit groups need the
-                    # per-inserting-request granularity
-                    brk[1:] = ((ifi[1:] != ifi[:-1])
-                               | (iuc[1:] != iuc[:-1] + 1))
-                else:
-                    # global mode: size records only feed the size map and
-                    # byte accounting, both invariant under merging
-                    # contiguous equal-size runs — and per-object chunk
-                    # sizes rarely change, so this collapses a phase's
-                    # inserts to ~one splice per object
-                    ipc = pc_b[ifi]
-                    iob = obj_a[i + ifi]
-                    brk[1:] = ((ipc[1:] != ipc[:-1]) | (iob[1:] != iob[:-1])
-                               | (iuc[1:] != iuc[:-1] + 1))
+                # size records only feed the size map and byte accounting,
+                # both invariant under merging contiguous equal-size runs —
+                # and per-object chunk sizes rarely change, so this
+                # collapses a phase's inserts to ~one splice per object
+                ipc = pc_b[ifi]
+                iob = obj_a[i + ifi]
+                brk[1:] = ((ipc[1:] != ipc[:-1]) | (iob[1:] != iob[:-1])
+                           | (iuc[1:] != iuc[:-1] + 1))
                 gs = brk.nonzero()[0]
                 ge = np.append(gs[1:], len(iuc)) - 1
                 if flat:
                     # hand the column arrays straight to the flat state
                     z_parts = (obj_a[i + ifi[gs]], C[iuc[gs]],
-                               C[iuc[ge] + 1], pos_a[i + ifi[gs]],
-                               pc_b[ifi[gs]])
+                               C[iuc[ge] + 1], pc_b[ifi[gs]])
                 else:
                     size_recs = list(zip(
                         obj_a[i + ifi[gs]].tolist(), C[iuc[gs]].tolist(),
-                        C[iuc[ge] + 1].tolist(), pos_a[i + ifi[gs]].tolist(),
-                        pc_b[ifi[gs]].tolist()))
+                        C[iuc[ge] + 1].tolist(), pc_b[ifi[gs]].tolist()))
             # final recency order: (last toucher, hit/peer/origin phase,
             # ascending key) — single-touch inserts carry their phase, every
             # re-touched cell ends as a plain hit touch of its last toucher
@@ -1869,52 +1721,43 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
                 ph = np.where(single, np.where(acc2[d, uc], 1, 2), 0)
             else:
                 ph = np.where(single, 2, 0)
-            src_rec = np.where(single, pos_a[i + la], -1)
             o3 = np.lexsort((uc, ph, la))
-            uc3 = uc[o3]; ph3 = ph[o3]
-            la3 = la[o3]; sr3 = src_rec[o3]
+            uc3 = uc[o3]
+            la3 = la[o3]
             brk = np.empty(len(uc3), bool)
             brk[0] = True
-            r_grp = None
-            if log:
-                brk[1:] = ((la3[1:] != la3[:-1]) | (ph3[1:] != ph3[:-1])
-                           | (uc3[1:] != uc3[:-1] + 1))
-            else:
-                # global mode: the FIFO consumes records front-to-back and
-                # chunks ascending within a record, so records adjacent in
-                # commit order with contiguous ascending keys evict
-                # identically whether split or merged — and ``src`` is only
-                # consulted by the log-mode audit.  Merge maximally: only a
-                # key gap or an object change forces a new record.  Shorter
-                # FIFOs make every later eviction scan cheaper.
-                ob3 = obj_a[i + la3]
-                brk[1:] = (uc3[1:] != uc3[:-1] + 1) | (ob3[1:] != ob3[:-1])
-                # group fusion: consecutive records of one object with
-                # strictly ascending (gap-allowed) key runs share ONE rid
-                # and ONE FIFO record — ascending disjoint runs under a
-                # single rid consume front-to-back exactly like adjacent
-                # split records, and the gaps' keys belong to other rids
-                # (evictions filter by rid ownership).  A group boundary is
-                # a subset condition of a record boundary, so ``r_grp`` is
-                # piecewise-constant over the ``gs`` records.
-                grp_brk = np.empty(len(uc3), bool)
-                grp_brk[0] = True
-                grp_brk[1:] = ((uc3[1:] <= uc3[:-1]) | (ob3[1:] != ob3[:-1]))
+            # the FIFO consumes records front-to-back and chunks ascending
+            # within a record, so records adjacent in commit order with
+            # contiguous ascending keys evict identically whether split or
+            # merged.  Merge maximally: only a key gap or an object change
+            # forces a new record.  Shorter FIFOs make every later eviction
+            # scan cheaper.
+            ob3 = obj_a[i + la3]
+            brk[1:] = (uc3[1:] != uc3[:-1] + 1) | (ob3[1:] != ob3[:-1])
+            # group fusion: consecutive records of one object with strictly
+            # ascending (gap-allowed) key runs share ONE rid and ONE FIFO
+            # record — ascending disjoint runs under a single rid consume
+            # front-to-back exactly like adjacent split records, and the
+            # gaps' keys belong to other rids (evictions filter by rid
+            # ownership).  A group boundary is a subset condition of a
+            # record boundary, so ``r_grp`` is piecewise-constant over the
+            # ``gs`` records.
+            grp_brk = np.empty(len(uc3), bool)
+            grp_brk[0] = True
+            grp_brk[1:] = ((uc3[1:] <= uc3[:-1]) | (ob3[1:] != ob3[:-1]))
             gs = brk.nonzero()[0]
             ge = np.append(gs[1:], len(uc3)) - 1
-            if not log:
-                r_grp = np.cumsum(grp_brk[gs]) - 1
+            r_grp = np.cumsum(grp_brk[gs]) - 1
             if flat:
                 if z_parts is None:
                     e_ = np.empty(0, np.int64)
-                    z_parts = (e_, e_, e_, e_, e_)
-                st.commit_block_arrays(*z_parts, obj_a[i + la3[gs]],
-                                       C[uc3[gs]], C[uc3[ge] + 1], sr3[gs],
-                                       r_grp)
+                    z_parts = (e_, e_, e_, e_)
+                st.commit_block_arrays(*z_parts, C[uc3[gs]],
+                                       C[uc3[ge] + 1], r_grp)
             else:
                 rec_recs = list(zip(
                     obj_a[i + la3[gs]].tolist(), C[uc3[gs]].tolist(),
-                    C[uc3[ge] + 1].tolist(), sr3[gs].tolist()))
+                    C[uc3[ge] + 1].tolist()))
                 st.commit_block(size_recs, rec_recs, r_grp)
 
         def commit_phase(p0: int, b1: int) -> None:
@@ -2030,22 +1873,21 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
             st.hit_bytes += int((nh_b[md] * pc_b[md]).sum())
             st.misses += int(nm_b[md].sum())
             st.miss_bytes += int((nm_b[md] * pc_b[md]).sum())
-        if not log:
-            nh_loc[i:j] = nh_b
-            if n_ins:
-                na = np.bincount(ins_inc[acc], weights=ins_len[acc],
-                                 minlength=B).astype(np.int64)
-                acc_loc[i:j] = na
-                still_loc[i:j] = nm_b - na
-                if acc.any():
-                    pdt_loc[i:j] = np.bincount(
-                        ins_inc[acc],
-                        weights=ins_len[acc]
-                        * (pc_b[ins_inc[acc]] / best_bw[acc]),
-                        minlength=B)
-                    peer_ranges.extend(coalesce_peer_ranges(
-                        pos_a[i + ins_inc[acc]], ins_d[acc], src[acc],
-                        C[ins_cell[acc]], C[ins_cell[acc] + 1]))
+        nh_loc[i:j] = nh_b
+        if n_ins:
+            na = np.bincount(ins_inc[acc], weights=ins_len[acc],
+                             minlength=B).astype(np.int64)
+            acc_loc[i:j] = na
+            still_loc[i:j] = nm_b - na
+            if acc.any():
+                pdt_loc[i:j] = np.bincount(
+                    ins_inc[acc],
+                    weights=ins_len[acc]
+                    * (pc_b[ins_inc[acc]] / best_bw[acc]),
+                    minlength=B)
+                peer_ranges.extend(coalesce_peer_ranges(
+                    pos_a[i + ins_inc[acc]], ins_d[acc], src[acc],
+                    C[ins_cell[acc]], C[ins_cell[acc] + 1]))
         i = j
         if was_trunc:
             ctr["trunc"] += 1
@@ -2072,274 +1914,93 @@ def _fused_block_replay(states: dict, bw, enable_peer: bool, log: bool,
     if blk_state is not None:
         blk_state["blk"] = blk
         blk_state["degen"] = degen
-    if log:
-        return None
     return nh_loc, acc_loc, pdt_loc, still_loc, peer_ranges
 
 
-def _interval_replay_payload(capacity: int, idx: list, obj: list, lo: list,
-                             kk: list, pc: list, fused: bool = False,
-                             flat: bool = False) -> dict:
-    """Phase A for one DTN: replay its request subsequence through an
-    :class:`IntervalLRUState` (or :class:`FlatIntervalState` when ``flat``)
-    and package the logs for phase B — request by request, or through the
-    fused block path in the coarse regime."""
-    st = FlatIntervalState(capacity) if flat else IntervalLRUState(capacity)
-    if fused:
-        n = len(idx)
-        lo_a = np.asarray(lo, np.int64)
-        # single-DTN replay: the DTN id is never consulted in log mode
-        _fused_block_replay({1: st}, None, False, True,
-                            np.asarray(idx, np.int64),
-                            np.ones(n, np.int64),
-                            np.asarray(obj, np.int64), lo_a,
-                            lo_a + np.asarray(kk, np.int64),
-                            np.asarray(pc, np.int64))
-    else:
-        serve = st.serve
-        for i_, o_, l_, k_, p_ in zip(idx, obj, lo, kk, pc):
-            serve(i_, o_, l_, l_ + k_, p_)
-
-    def log3(log: list) -> np.ndarray:
-        flat = np.fromiter(itertools.chain.from_iterable(log), np.int64,
-                           count=3 * len(log))
-        return flat.reshape(-1, 3)
-
-    return dict(
-        counters=(st.hits, st.misses, st.hit_bytes, st.miss_bytes,
-                  st.evictions, st.inserted_bytes),
-        miss=log3(st.miss_log), ins=log3(st.insert_log),
-        ev=log3(st.evict_log), splits=st.split_log,
-    )
-
-
-def _interval_worker_main(conn, capacity: int, jobs: list,
-                          fused: bool = False, flat: bool = False) -> None:
-    """Forked shard worker: replay a bin of DTNs, ship payloads back."""
-    try:
-        out = {d: _interval_replay_payload(capacity, *job, fused=fused,
-                                           flat=flat)
-               for d, job in jobs}
-        conn.send((True, out))
-    except BaseException as e:          # surfaced in the driver
-        conn.send((False, repr(e)))
-    finally:
-        conn.close()
-
-
 class IntervalVDCSimulator(VectorVDCSimulator):
-    """Third replay engine: interval-algebra presence tracking plus the
-    sharded multi-DTN replay driver (see the module-section comment above).
+    """Third replay engine: interval-algebra presence tracking (see the
+    module-section comment above).
 
-    Drop-in for the other engines.  The static LRU serving path goes
-    through a small *replay planner*:
+    Drop-in for the other engines.  The static LRU serving path picks its
+    route from the first window's mean chunk positions per live request
+    (for a materialized trace, the whole trace's):
 
-    - in the **coarse regime** (mean chunk positions per live request below
-      ``SWEEP_MIN_CHUNKS_PER_REQ``) it runs the **fused block-over-
-      intervals replay** (:meth:`_run_fused` / :func:`_fused_block_replay`):
-      the vector engine's block discipline — block-start snapshot,
-      first/last-coverage classification, truncation so nothing in-block is
-      ever evicted — executed directly on :class:`IntervalLRUState`, with
-      run-level peer resolution, run-merge commits and run-split evictions
-      instead of per-chunk radix sorts and scatters;
+    - in the **coarse regime** (below ``SWEEP_MIN_CHUNKS_PER_REQ``) it runs
+      the **fused block-over-intervals replay**
+      (:func:`_fused_block_replay`): the vector engine's block discipline —
+      block-start snapshot, first/last-coverage classification, truncation
+      so nothing in-block is ever evicted — executed directly on interval
+      states, with run-level peer resolution, run-merge commits and
+      run-split evictions instead of per-chunk radix sorts and scatters;
     - in the **fine-chunking regime** (sub-five-minute chunks on the
-      paper's traces) it runs the sequential global sweep
-      (:meth:`_run_sweep`), whose per-request cost is governed by *segment*
-      counts, not chunk counts;
-    - ``SimConfig.interval_shards > 1`` opts into the optimistic sharded
-      driver (:meth:`_run_sharded`), whose per-DTN phase A itself uses the
-      fused block path in the coarse regime; ``interval_shards = 1`` pins
-      the sequential sweep.
+      paper's traces) it runs the per-request interval sweep
+      (:func:`_sweep_serve`), whose per-request cost is governed by
+      *segment* counts, not chunk counts.
 
-    Strategies with dynamic events (prefetch / streaming / placement), LFU
-    caches and ``use_cache=False`` runs always delegate to the inherited
+    Tests pin a route by patching ``SWEEP_MIN_CHUNKS_PER_REQ`` (0 forces
+    the sweep, ``inf`` the fused replay).  Strategies with dynamic events
+    (prefetch / streaming / placement), LFU caches, ``use_cache=False``
+    runs and sources without a ``tr_bounds`` hint delegate to the inherited
     vector paths.  All routes produce identical integer counters
     (``tests/test_engine_equivalence.py``, ``tests/test_engine_fuzz.py``).
     """
 
-    #: auto-planner threshold: mean chunk positions per live request above
-    #: which the interval sweep beats block replay (measured crossover on
-    #: the 2-core reference container lies between 55 and 280)
+    #: route threshold: mean chunk positions per live request above which
+    #: the interval sweep beats block replay (measured crossover on the
+    #: 2-core reference container lies between 55 and 280)
     SWEEP_MIN_CHUNKS_PER_REQ = 96.0
 
-    #: filled by the last static interval run: accepted peer transfers as
-    #: coalesced (req_pos, dtn, src, key_lo, key_hi) ranges
+    #: filled by the last static interval run of a materialized trace:
+    #: accepted peer transfers as coalesced (req_pos, dtn, src, key_lo,
+    #: key_hi) ranges
     last_peer_fetches: list
 
-    def run(self, requests: Sequence[Request], name: str = "") -> SimResult:
+    def _run_stream(self, source: StreamingRequestSource, name: str = "",
+                    keep_outcomes: bool = False) -> SimResult:
         self.last_peer_fetches = []
         stream_engine = getattr(self.pf, "streaming", None)
         static = (self.placement is None and stream_engine is None
                   and getattr(self.pf, "static", False))
-        eligible = (static and self.use_cache
-                    and self.cfg.cache_policy.lower() == "lru")
-        if isinstance(requests, StreamingRequestSource):
-            # The sharded driver needs whole-trace event logs for its audit,
-            # and a source without a tr-bounds hint cannot pre-size the key
-            # space; both fall back to the inherited (equally exact) vector
-            # streaming path.  ``last_peer_fetches`` stays empty in
-            # streaming mode — accumulating it would grow with the trace.
-            if (eligible and requests.tr_bounds is not None
-                    and self._resolve_workers(self.n_dtn) <= 1):
-                return self._run_stream_interval(requests, name)
-            return super().run(requests, name)
-        if not eligible:
-            return super().run(requests, name)
-        return self._run_static_interval(requests, name)
+        # a source without a tr-bounds hint cannot pre-size the key space:
+        # it takes the inherited (equally exact) vector path
+        if (static and self.use_cache and source.tr_bounds is not None
+                and self.cfg.cache_policy.lower() == "lru"):
+            return self._run_stream_interval(source, name, keep_outcomes)
+        return super()._run_stream(source, name, keep_outcomes)
 
-    # -- phase A -------------------------------------------------------------
-
-    def _resolve_workers(self, n_jobs: int) -> int:
-        # Default: the sequential global sweep.  Its inline peer resolution
-        # is unconditionally exact, and on skewed traces (OOI routes ~68%
-        # of requests to one DTN) per-DTN sharding cannot amortize its fork
-        # and result-shipping overhead on a small host.  Explicit
-        # ``interval_shards > 1`` opts into the optimistic sharded driver,
-        # which shines on balanced traces / many-core machines.
-        w = self.cfg.interval_shards
-        if w is None:
-            return 1
-        # an explicit shard count is honored even past os.cpu_count():
-        # oversubscription only costs scheduling, while clamping would
-        # silently reduce the sharded driver to the sweep on small hosts
-        # (leaving the `interval_shards=2` contract untested on 1-core CI)
-        return max(1, min(int(w), n_jobs))
-
-    def _phase_a(self, P: dict) -> dict[int, dict]:
-        dtn_arr = P["dtn"]
-        live = ~P["zero"]
-        obj_arr, base = P["obj"], P["base"]
-        k_eff, per_chunk = P["k_eff"], P["pc"]
-        # in the coarse regime each per-DTN replay itself goes through the
-        # fused block path; in the fine regime the per-request interval
-        # sweep already wins (segment-bound, not chunk-bound)
-        fused = P["mean_k"] < self.SWEEP_MIN_CHUNKS_PER_REQ
-        # the flat state only batches the fused block APIs; the per-request
-        # sweep regime stays on the list state (segment-bound splices win
-        # there — see docs/ARCHITECTURE.md)
-        flat = fused and self.cfg.interval_flat_state
-        jobs: dict[int, tuple] = {}
-        loads: list[tuple[int, int]] = []
-        for d in range(1, self.n_dtn):
-            sel = np.nonzero(live & (dtn_arr == d))[0]
-            if len(sel):
-                jobs[d] = (sel.tolist(), obj_arr[sel].tolist(),
-                           base[sel].tolist(), k_eff[sel].tolist(),
-                           per_chunk[sel].tolist())
-                loads.append((len(sel), d))
+    def _route(self, mean_k: float) -> tuple[dict, dict | None]:
+        """Fresh per-DTN interval states for the route that ``mean_k``
+        (mean chunk positions per live request) picks, plus the sweep's
+        peer candidates (``None`` on the fused route)."""
         cap = self.cfg.cache_bytes
-        n_workers = self._resolve_workers(len(jobs))
-        if n_workers <= 1:
-            return {d: _interval_replay_payload(cap, *jobs[d], fused=fused,
-                                                flat=flat)
-                    for d in jobs}
-        # greedy bin-packing by request count; the driver replays the
-        # heaviest bin itself while forked workers handle the rest.
-        # Deterministic tie-breaks everywhere (equal loads sort by DTN id,
-        # equal bins by their smallest DTN id) so repeated runs pack — and
-        # therefore replay — identically
-        loads.sort(key=lambda t: (-t[0], t[1]))
-        bins: list[list[int]] = [[] for _ in range(n_workers)]
-        totals = [0] * n_workers
-        for load, d in loads:
-            i = totals.index(min(totals))
-            bins[i].append(d)
-            totals[i] += load
-        bins = [b for b in bins if b]
-        bins.sort(key=lambda b: (-sum(len(jobs[d][0]) for d in b), min(b)))
-        try:
-            ctx = multiprocessing.get_context("fork")
-        except ValueError:                       # no fork on this platform
-            return {d: _interval_replay_payload(cap, *jobs[d], fused=fused,
-                                                flat=flat)
-                    for d in jobs}
-        procs = []
-        for b in bins[1:]:
-            parent_conn, child_conn = ctx.Pipe(duplex=False)
-            p = ctx.Process(target=_interval_worker_main,
-                            args=(child_conn, cap,
-                                  [(d, jobs[d]) for d in b], fused, flat),
-                            daemon=True)
-            p.start()
-            child_conn.close()
-            procs.append((p, parent_conn))
-        payloads = {d: _interval_replay_payload(cap, *jobs[d], fused=fused,
-                                                flat=flat)
-                    for d in bins[0]}
-        for p, conn in procs:
-            ok, out = conn.recv()
-            conn.close()
-            p.join()
-            if not ok:
-                raise RuntimeError(f"interval shard worker failed: {out}")
-            payloads.update(out)
-        return payloads
+        fused = mean_k < self.SWEEP_MIN_CHUNKS_PER_REQ
+        cls = (FlatIntervalState if fused and self.cfg.interval_flat_state
+               else IntervalLRUState)
+        self.caches = {d: cls(cap) for d in range(1, self.n_dtn)}
+        return self.caches, (None if fused
+                             else _peer_cands(self.bw, self.n_dtn))
 
-    # -- dispatcher ----------------------------------------------------------
-
-    def _run_static_interval(self, requests: Sequence[Request],
-                             name: str) -> SimResult:
-        cfg = self.cfg
-        arr = requests_to_arrays(requests)
-        n_req = len(arr)
-        scale = 1.0 / cfg.traffic_scale
-        now_arr = arr.ts * scale
-        first, n_chunks = chunk_bounds_bulk(
-            arr.tr_start, np.minimum(arr.tr_end, now_arr), cfg.chunk_seconds)
-        zero = (n_chunks == 0) | (arr.size_bytes == 0)
-        k_eff = np.where(zero, 0, n_chunks)
-        per_chunk = np.maximum(1, arr.size_bytes // np.maximum(1, n_chunks))
-        dtn_arr = arr.continent + 1
-        live = k_eff > 0
-        if live.any():
-            lo_min = int(first[live].min())
-            hi_max = int((first + k_eff)[live].max())
-        else:
-            lo_min, hi_max = 0, 1
-        off = max(0, -lo_min) + 8
-        span = hi_max + off + 8
-        n_live = int(live.sum())
-        mean_k = float(k_eff[live].sum()) / n_live if n_live else 0.0
-        P = dict(arr=arr, n_req=n_req, now=now_arr, zero=zero, k_eff=k_eff,
-                 pc=per_chunk, dtn=dtn_arr, obj=arr.obj,
-                 base=arr.obj * span + first + off, mean_k=mean_k)
-        out = None
-        if cfg.interval_shards is None:
-            if mean_k < self.SWEEP_MIN_CHUNKS_PER_REQ:
-                # coarse regime: the fused block-over-intervals replay
-                # (inline peers against block snapshots — always exact)
-                out = self._run_fused(P)
-            # fine regime: the sequential sweep below
-        elif self._resolve_workers(int(np.unique(dtn_arr[~zero]).size
-                                       or 1)) > 1:
-            try:
-                out = self._run_sharded(P)
-            except _IntervalOrderAmbiguity:
-                # a logged eviction split was sensitive to the true peer-vs-
-                # origin insert order: discard the optimistic replay and run
-                # the exact sequential sweep
-                out = None
-        if out is None:
-            out = self._run_sweep(P)
-        return self._finish(P, out, name)
-
-    # -- streaming entry (windowed static-LRU interval replay) ---------------
+    # -- windowed static-LRU interval replay ---------------------------------
 
     def _run_stream_interval(self, source: StreamingRequestSource,
-                             name: str) -> SimResult:
+                             name: str,
+                             keep_outcomes: bool = False) -> SimResult:
         """Static-LRU interval replay over a windowed source.
 
         The dense key space is fixed up front from the source's
         ``tr_bounds`` hint instead of the trace's observed chunk extremes.
         That is a pure renaming of chunk keys — per-object key ranges stay
-        separated by >= 8 keys, so run merges, commits and evictions are
-        position-identical to the materialized run — which lets every
-        window share one address space with no remapping.  Interval states,
-        the sweep's peer-candidate order, the fused/sweep route (picked
-        from the first window's mean chunk count) and phase C's origin
-        queue persist across windows; per-request state is recomputed per
+        separated by >= 8 keys, so run merges, commits and evictions do not
+        depend on the bound — which lets every window share one address
+        space with no remapping.  Interval states, the sweep's
+        peer-candidate order, the fused/sweep route (picked from the first
+        window's mean chunk count) and the origin queue persist across
+        windows; per-request state is recomputed per
         window, so peak memory is bounded by the window size plus the
-        capacity-bounded interval sets."""
+        capacity-bounded interval sets.  With ``keep_outcomes`` (a
+        materialized trace, replayed as one window) that window's columns
+        become the per-request ``outcomes`` and its accepted peer ranges
+        ``last_peer_fetches``."""
         cfg = self.cfg
         cs = cfg.chunk_seconds
         tr_lo, tr_hi = source.tr_bounds
@@ -2348,15 +2009,15 @@ class IntervalVDCSimulator(VectorVDCSimulator):
         off = max(0, -c_lo) + 8
         span = c_hi + off + 8
         scale = 1.0 / cfg.traffic_scale
-        cap = cfg.cache_bytes
         states: dict | None = None
         sweep_cands = None
+        outcomes: Sequence[RequestOutcome] = []
         free = [0.0] * cfg.n_service_procs
         ov = cfg.origin_latency_s
         bw0 = self._bw0
         inf = float("inf")
         submit = origin_submit
-        agg = OutcomeAggregate()
+        agg = None if keep_outcomes else OutcomeAggregate()
         origin_requests = 0
         n_total = 0
         pos0 = 0
@@ -2383,16 +2044,7 @@ class IntervalVDCSimulator(VectorVDCSimulator):
             if states is None:
                 n_live = len(live)
                 mean_k = (float(k_eff[live].sum()) / n_live) if n_live else 0.0
-                fused = (cfg.interval_shards is None
-                         and mean_k < self.SWEEP_MIN_CHUNKS_PER_REQ)
-                cls = (FlatIntervalState
-                       if (fused and cfg.interval_flat_state)
-                       else IntervalLRUState)
-                states = {d: cls(cap, log_events=False)
-                          for d in range(1, self.n_dtn)}
-                self.caches = states
-                if not fused:
-                    sweep_cands = _peer_cands(self.bw, self.n_dtn)
+                states, sweep_cands = self._route(mean_k)
             base = arr.obj * span + first + off
             lo_a = base[live]
             nh_full = np.zeros(n_req, np.int64)
@@ -2400,8 +2052,8 @@ class IntervalVDCSimulator(VectorVDCSimulator):
             o_pt = np.zeros(n_req, np.float64)
             n_still = np.zeros(n_req, np.int64)
             if sweep_cands is None:
-                nh_l, acc_l, pdt_l, still_l, _ = _fused_block_replay(
-                    states, self.bw, cfg.enable_peer_cache, False,
+                nh_l, acc_l, pdt_l, still_l, peer_ranges = _fused_block_replay(
+                    states, self.bw, cfg.enable_peer_cache,
                     pos0 + live, dtn_arr[live], arr.obj[live], lo_a,
                     lo_a + k_eff[live], per_chunk[live], ctr=self._ctr,
                     blk_state=blk_state)
@@ -2412,7 +2064,7 @@ class IntervalVDCSimulator(VectorVDCSimulator):
                 tra[live] += pdt_l
                 n_still[live] = still_l
             else:
-                peer_ranges: list = []   # window-local, dropped (bounded mem)
+                peer_ranges = []
                 nh_l, miss_pos, miss_acc, miss_pdt, miss_still = _sweep_serve(
                     states, sweep_cands, cfg.enable_peer_cache,
                     dtn_arr[live].tolist(), arr.obj[live].tolist(),
@@ -2428,9 +2080,9 @@ class IntervalVDCSimulator(VectorVDCSimulator):
                     o_pt[midx] = miss_pdt
                     tra[midx] += miss_pdt
                     n_still[midx] = miss_still
-            # phase C against the persistent origin queue: the submit
-            # sequence is the trace-order (now, duration) sequence, so
-            # per-window replay is arithmetic-identical to whole-trace
+            # origin-queue replay against the persistent queue: the submit
+            # sequence is the trace-order (now, duration) sequence, so any
+            # window split gives identical arithmetic
             o_lat = np.zeros(n_req, np.float64)
             o_org = np.zeros(n_req, np.int64)
             nz = np.nonzero(n_still)[0]
@@ -2448,21 +2100,27 @@ class IntervalVDCSimulator(VectorVDCSimulator):
                 o_lat[nz] = lat_l
                 tra[nz] += dtr_l
                 o_org[nz] = per_chunk[nz] * n_still[nz]
-            o_loc = nh_full * per_chunk
-            o_bytes = np.where(zero, 0, arr.size_bytes)
-            agg.add_columns(o_bytes, o_lat, tra, o_loc,
-                            np.zeros(n_req, np.int64), o_peer, o_org, o_pt)
+            cols = (np.where(zero, 0, arr.size_bytes), o_lat, tra,
+                    nh_full * per_chunk, np.zeros(n_req, np.int64), o_peer,
+                    o_org, o_pt)
+            if keep_outcomes:
+                outcomes = _LazyOutcomes((now_arr, arr.user_id) + cols)
+                self.last_peer_fetches = peer_ranges
+            else:
+                # window-local peer ranges are dropped (bounded memory)
+                agg.add_columns(*cols)
             origin_requests += int((o_org > 0).sum())
             n_total += n_req
             pos0 += n_req
         if states is None:
-            states = {d: IntervalLRUState(cap, log_events=False)
-                      for d in range(1, self.n_dtn)}
-            self.caches = states
+            states, _ = self._route(0.0)
+            if keep_outcomes:
+                e = np.empty(0, np.int64)
+                outcomes = _LazyOutcomes((e,) * 10)
         stats = {d: st.to_cache_stats() for d, st in states.items()}
         return SimResult(
             name=name or self.pf.name,
-            outcomes=[],
+            outcomes=outcomes,
             origin_requests=origin_requests,
             total_requests=n_total,
             prefetch_issued_chunks=0,
@@ -2470,247 +2128,6 @@ class IntervalVDCSimulator(VectorVDCSimulator):
             cache_stats=stats,
             stream_pushes=0,
             aggregate=agg,
-            evict_plan_calls=self._ctr["plan"],
-            block_truncations=self._ctr["trunc"],
-            degenerate_serves=self._ctr["degen"],
-            block_phases=self._ctr["phases"],
-            inblock_victims=self._ctr["invict"],
-        )
-
-    # -- global fused block replay (coarse-regime default) -------------------
-
-    def _run_fused(self, P: dict) -> dict:
-        """Replay the whole trace through :func:`_fused_block_replay`: the
-        vector engine's block discipline (snapshot + truncation) executed
-        on interval state, with run-level peer resolution and commits."""
-        cfg = self.cfg
-        n_req = P["n_req"]
-        live = np.nonzero(~P["zero"])[0]
-        lo_a = P["base"][live]
-        cap = cfg.cache_bytes
-        cls = (FlatIntervalState if cfg.interval_flat_state
-               else IntervalLRUState)
-        states = {d: cls(cap, log_events=False)
-                  for d in range(1, self.n_dtn)}
-        nh_l, acc_l, pdt_l, still_l, peer_ranges = _fused_block_replay(
-            states, self.bw, cfg.enable_peer_cache, False,
-            live, P["dtn"][live], P["obj"][live], lo_a,
-            lo_a + P["k_eff"][live], P["pc"][live], ctr=self._ctr)
-        per_chunk = P["pc"]
-        nh_full = np.zeros(n_req, np.int64)
-        nh_full[live] = nh_l
-        o_peer = np.zeros(n_req, np.int64)
-        o_peer[live] = acc_l * P["pc"][live]
-        o_pt = np.zeros(n_req, np.float64)
-        o_pt[live] = pdt_l
-        tra = nh_full * (per_chunk / self._ulink)
-        tra[live] += pdt_l
-        n_still_arr = np.zeros(n_req, np.int64)
-        n_still_arr[live] = still_l
-        stats = {d: st.to_cache_stats() for d, st in states.items()}
-        self.caches = states
-        return dict(nh=nh_full, tra=tra, o_peer=o_peer, o_pt=o_pt,
-                    n_still=n_still_arr, stats=stats,
-                    peer_ranges=peer_ranges)
-
-    # -- sequential global sweep (inline peer resolution; always exact) ------
-
-    def _run_sweep(self, P: dict) -> dict:
-        """Replay the whole trace in order, one DTN cache state per DTN:
-        hit/miss split and LRU touch by interval intersection, peer fetch
-        ranges resolved *inline* against the other caches' current coverage
-        (so the reference's peer-before-origin insert order is applied
-        exactly, with no audit needed), origin-queue submits deferred to a
-        trace-order replay after the sweep."""
-        cfg = self.cfg
-        n_req = P["n_req"]
-        live = np.nonzero(~P["zero"])[0]
-        idx_l = live.tolist()
-        dtn_l = P["dtn"][live].tolist()
-        obj_l = P["obj"][live].tolist()
-        lo_l = P["base"][live].tolist()
-        k_l = P["k_eff"][live].tolist()
-        pc_l = P["pc"][live].tolist()
-        cap = cfg.cache_bytes
-        states = {d: IntervalLRUState(cap, log_events=False)
-                  for d in range(1, self.n_dtn)}
-        cands = _peer_cands(self.bw, self.n_dtn)
-        peer_ranges: list[tuple] = []
-        nh_l, miss_pos, miss_acc, miss_pdt, miss_still = _sweep_serve(
-            states, cands, cfg.enable_peer_cache, dtn_l, obj_l, lo_l, k_l,
-            pc_l, idx_l, peer_ranges)
-        per_chunk = P["pc"]
-        nh_full = np.zeros(n_req, np.int64)
-        nh_full[live] = nh_l
-        o_peer = np.zeros(n_req, np.int64)
-        o_pt = np.zeros(n_req, np.float64)
-        tra = nh_full * (per_chunk / self._ulink)
-        n_still_arr = np.zeros(n_req, np.int64)
-        if miss_pos:
-            midx = live[miss_pos]
-            o_peer[midx] = np.asarray(miss_acc, np.int64) * per_chunk[midx]
-            o_pt[midx] = miss_pdt
-            tra[midx] += miss_pdt
-            n_still_arr[midx] = miss_still
-        stats = {d: st.to_cache_stats() for d, st in states.items()}
-        self.caches = states
-        return dict(nh=nh_full, tra=tra, o_peer=o_peer, o_pt=o_pt,
-                    n_still=n_still_arr, stats=stats,
-                    peer_ranges=peer_ranges)
-
-    # -- sharded driver (optimistic per-DTN phase A + audited phase B) -------
-
-    def _run_sharded(self, P: dict) -> dict:
-        """Phases A (parallel per-DTN sweeps) and B (timeline-based peer
-        resolution + exactness audit); raises
-        :class:`_IntervalOrderAmbiguity` when an eviction split event is
-        order-sensitive."""
-        n_req = P["n_req"]
-        payloads = self._phase_a(P)
-        # the per-DTN cache states live (and die) in the shard workers;
-        # only their logs/counters come back — drop any stale state a
-        # previous run left on this simulator
-        self.caches = {}
-        per_chunk = P["pc"]
-        o_pt = np.zeros(n_req, np.float64)
-        o_peer = np.zeros(n_req, np.int64)
-        n_still = np.zeros(n_req, np.int64)
-        nh_arr = P["k_eff"].copy()
-        tra = np.zeros(n_req, np.float64)
-        timelines: dict[int, PresenceTimeline] = {}
-
-        def timeline(d: int) -> PresenceTimeline:
-            tl = timelines.get(d)
-            if tl is None:
-                pay = payloads.get(d)
-                e = np.empty((0, 3), np.int64)
-                tl = PresenceTimeline(pay["ins"] if pay else e,
-                                      pay["ev"] if pay else e, n_req)
-                timelines[d] = tl
-            return tl
-
-        bw = self.bw
-        split_checks: list[tuple] = []
-        peer_ranges: list = []
-        for d, pay in sorted(payloads.items()):
-            miss = pay["miss"]
-            if not len(miss):
-                continue
-            keys, req_rep = _ranges_to_chunks(miss[:, 0], miss[:, 1],
-                                              miss[:, 2])
-            nm = len(keys)
-            best_bw = np.zeros(nm, np.float64)
-            src = np.zeros(nm, np.int64)
-            origin_bw = float(bw[0, d])
-            if self.cfg.enable_peer_cache:
-                for d2 in range(1, self.n_dtn):
-                    b2 = float(bw[d2, d])
-                    if d2 == d or b2 <= origin_bw or b2 <= 0.0:
-                        continue               # can never win acceptance
-                    held = timeline(d2).query(keys, req_rep)
-                    upd = held & (b2 > best_bw)
-                    if upd.any():
-                        best_bw[upd] = b2
-                        src[upd] = d2
-            acc = best_bw > origin_bw
-            n_miss_req = np.bincount(req_rep, minlength=n_req)
-            nh_arr -= n_miss_req
-            n_acc = np.bincount(req_rep[acc], minlength=n_req)
-            if acc.any():
-                pcs = per_chunk[req_rep[acc]]
-                dt = np.bincount(req_rep[acc], weights=pcs / best_bw[acc],
-                                 minlength=n_req)
-                o_peer += n_acc * per_chunk
-                o_pt += dt
-                tra += dt
-                peer_ranges.extend(coalesce_peer_fetches(
-                    req_rep[acc], keys[acc], src[acc], d))
-            n_still += n_miss_req - n_acc
-            # miss logs are appended in trace order, so req_rep is sorted:
-            # slice out each split request's accepted chunks by bisection
-            for s_req, evicted, remaining in pay["splits"]:
-                a_, b_ = np.searchsorted(req_rep, (s_req, s_req + 1))
-                sl = slice(int(a_), int(b_))
-                split_checks.append((evicted, remaining,
-                                     set(keys[sl][acc[sl]].tolist())))
-
-        # exactness audit: every eviction that consumed part of a request's
-        # insert group must be insensitive to the true peer-vs-origin
-        # insert order (the reference evicts the peer-fetched chunks of a
-        # request before its origin chunks — across ALL its records)
-        for evicted, remaining, accset in split_checks:
-            if remaining is None:
-                # mid-insert self-eviction: phase A's own trajectory depends
-                # on the order unless the request had no peer chunks at all
-                if accset:
-                    raise _IntervalOrderAmbiguity
-                continue
-            e_keys = [k for a, b in evicted for k in range(a, b)]
-            r_keys = [k for a, b in remaining for k in range(a, b)]
-            true_order = sorted(
-                e_keys + r_keys,
-                key=lambda k: (1 if k in accset else 2, k))
-            if set(true_order[:len(e_keys)]) != set(e_keys):
-                raise _IntervalOrderAmbiguity
-
-        tra += nh_arr * (per_chunk / self._ulink)
-        stats = {}
-        for d in range(1, self.n_dtn):
-            pay = payloads.get(d)
-            stats[d] = CacheStats(*pay["counters"]) if pay else CacheStats()
-        return dict(nh=nh_arr, tra=tra, o_peer=o_peer, o_pt=o_pt,
-                    n_still=n_still, stats=stats, peer_ranges=peer_ranges)
-
-    # -- phase C + result assembly -------------------------------------------
-
-    def _finish(self, P: dict, out: dict, name: str) -> SimResult:
-        """Sequential origin-queue replay in trace order (identical float
-        arithmetic to the reference) and :class:`SimResult` assembly."""
-        cfg = self.cfg
-        n_req = P["n_req"]
-        now_arr = P["now"]
-        per_chunk = P["pc"]
-        dtn_arr = P["dtn"]
-        n_still = out["n_still"]
-        tra = out["tra"]
-        o_lat = np.zeros(n_req, np.float64)
-        o_org = np.zeros(n_req, np.int64)
-        nz = np.nonzero(n_still)[0]
-        if len(nz):
-            free = [0.0] * cfg.n_service_procs
-            ov = cfg.origin_latency_s
-            bw0 = self._bw0
-            inf = float("inf")
-            submit = origin_submit
-            lat_l: list[float] = []
-            dtr_l: list[float] = []
-            ob_l = (per_chunk[nz] * n_still[nz]).tolist()
-            for now, d, ob in zip(now_arr[nz].tolist(),
-                                  dtn_arr[nz].tolist(), ob_l):
-                b = bw0[d]
-                start, end = submit(free, ov, now,
-                                    ob / b if b > 0.0 else inf)
-                lat_l.append(start - now)
-                dtr_l.append(end - start)
-            o_lat[nz] = lat_l
-            tra[nz] += dtr_l
-            o_org[nz] = per_chunk[nz] * n_still[nz]
-        self.last_peer_fetches = out["peer_ranges"]
-        o_loc = out["nh"] * per_chunk
-        arr = P["arr"]
-        o_bytes = np.where(P["zero"], 0, arr.size_bytes)
-        outcomes = _LazyOutcomes((
-            now_arr, arr.user_id, o_bytes, o_lat, tra, o_loc,
-            np.zeros(n_req, np.int64), out["o_peer"], o_org, out["o_pt"]))
-        return SimResult(
-            name=name or self.pf.name,
-            outcomes=outcomes,
-            origin_requests=int((o_org > 0).sum()),
-            total_requests=n_req,
-            prefetch_issued_chunks=0,
-            prefetch_used_chunks=0,
-            cache_stats=out["stats"],
-            stream_pushes=0,
             evict_plan_calls=self._ctr["plan"],
             block_truncations=self._ctr["trunc"],
             degenerate_serves=self._ctr["degen"],
@@ -2782,14 +2199,14 @@ def _sweep_serve(states: dict, cands: dict, enable_peer: bool,
                 unassigned = rem
             if acc_runs:
                 acc_runs.sort()
-                st.insert_runs(o, acc_runs, pc, ridx)
+                st.insert_runs(o, acc_runs, pc)
             still = unassigned
         else:
             still = miss
         n_still = 0
         if still:
             n_still = sum(b - a for a, b in still)
-            st.insert_runs(o, still, pc, ridx)
+            st.insert_runs(o, still, pc)
         miss_pos.append(pos)
         miss_acc.append(n_acc)
         miss_pdt.append(peer_dt)

@@ -348,8 +348,7 @@ class BatchedHPMPlanner:
     that user's request subsequence — cache state never feeds back — and
     the ARIMA bank's rows are batch-composition independent (pinned by
     ``test_bank_rows_independent_of_batch_composition``), so *any* window
-    split (width 1 → whole trace) emits the identical op stream; one
-    :meth:`plan` call on a fresh instance is just the single-window case.
+    split (width 1 → whole trace) emits the identical op stream.
     Phase-2 bank flushes happen once per window, bounding peak plan
     storage by the window size instead of the trace length.
     """
@@ -362,15 +361,12 @@ class BatchedHPMPlanner:
         self._rule_memo: dict[frozenset, list] = {}
         self._subscribed: set[tuple[int, int]] = set()
 
-    def plan(self, requests: Sequence[Request]) -> list[Sequence[PrefetchOp]]:
-        """Per-request op lists (``"stream"`` ops included) equal to what
-        ``observe`` would emit, without mutating the online model."""
-        return self.plan_window(requests)
-
     def plan_window(self, requests: Sequence[Request]
                     ) -> list[Sequence[PrefetchOp]]:
-        """Plan one timestamp-ordered window of the trace, carrying the
-        per-user classification state forward to the next call."""
+        """Per-request op lists (``"stream"`` ops included) equal to what
+        ``observe`` would emit for one timestamp-ordered window of the
+        trace, without mutating the online model; the per-user
+        classification state carries forward to the next call."""
         with telemetry.span("vdc.hpm.plan") as sp:
             model = self.model
             offset = model.offset

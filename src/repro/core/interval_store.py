@@ -3,7 +3,7 @@ serving target).
 
 :class:`FlatIntervalState` is a drop-in replacement for
 :class:`repro.core.cache.IntervalLRUState` — same API, same observable
-behavior (hit/miss/eviction counters, coverage, event logs), verified by the
+behavior (hit/miss/eviction counters, coverage), verified by the
 randomized differential fuzz in ``tests/test_interval_cache.py`` and the
 engine-level counter contract — with the Python-list run storage and deque
 FIFO replaced by flat numpy column arrays so the fused block replay's
@@ -31,7 +31,7 @@ Storage layout (all int64, amortized-doubling capacity, live prefix
   run) and are dropped by the next batched rebuild or by
   :meth:`_r_compact` when they pile up.  This keeps the hot eviction path
   free of array splices entirely.
-- **FIFO** ``(_fr, _flo, _fhi, _fsrc)[_fh:_ft]`` — the record queue as
+- **FIFO** ``(_fr, _flo, _fhi)[_fh:_ft]`` — the record queue as
   parallel arrays (record id, key range, inserting request or -1).
   ``_live[rid]`` (rid-indexed array) counts each record's live chunks, so
   stale records are skipped in O(1) and silently dropped when the queue
@@ -53,13 +53,12 @@ Batched kernels:
   ``searchsorted`` + rebuild pass merges a whole block's size records and
   recency records into each map (the engine hands the columns over as the
   arrays it already computed, skipping the list-of-tuples round trip);
-- :meth:`_evict_until` (non-log mode) — scans the FIFO in array batches:
+- :meth:`_evict_until` — scans the FIFO in array batches:
   per-record valid runs are gathered with two ``searchsorted`` calls, each
   run is priced via a cached byte-prefix over the size map, and the LRU
   cutoff is one ``cumsum``/``searchsorted``; only the final partially
   consumed run replays the reference's per-size-run ceil arithmetic
-  scalarly.  Log mode (the sharded driver's phase A) keeps the
-  per-record loop for exact ``evict_log``/``split_log`` granularity;
+  scalarly;
 - :meth:`plan_evict_clean` — the same batched scan as a pure dry run with
   a vectorized blocked-run stab, clamped at ``max_need`` (the fused block
   replay only compares the result against its byte shortfall — see the
@@ -75,8 +74,8 @@ differential fuzz):
   because all candidates are disjoint and present at call time;
 - sequential ``_evict_until`` calls with nondecreasing cumulative ``size``
   arguments equal one call with the final value (chunk-granular LRU
-  prefix consumption is monotone), which is why the engine's non-log path
-  may collapse a block's eviction loop into a single call.
+  prefix consumption is monotone), which is why the engine may collapse a
+  block's eviction loop into a single call.
 """
 from __future__ import annotations
 
@@ -188,11 +187,10 @@ class FlatIntervalState:
     #: engine dispatch marker: batched kernels accept array arguments
     flat = True
 
-    def __init__(self, capacity_bytes: int, log_events: bool = True):
+    def __init__(self, capacity_bytes: int):
         self.capacity = int(capacity_bytes)
         self.used = 0
         self.n_live = 0
-        self._log = log_events
         # recency map (may hold zero-length tombstones from evictions)
         self._rs = np.empty(64, _I64)
         self._re = np.empty(64, _I64)
@@ -206,11 +204,10 @@ class FlatIntervalState:
         self._zn = 0
         self._zcum = _EMPTY          # byte prefix over the size map
         self._zcum_ok = True
-        # FIFO of (rid, lo, hi, src) records, live slice [_fh:_ft)
+        # FIFO of (rid, lo, hi) records, live slice [_fh:_ft)
         self._fr = np.empty(64, _I64)
         self._flo = np.empty(64, _I64)
         self._fhi = np.empty(64, _I64)
-        self._fsrc = np.empty(64, _I64)
         self._fh = 0
         self._ft = 0
         # rid -> live chunk count (grown with _next_rid)
@@ -228,12 +225,6 @@ class FlatIntervalState:
         self.miss_bytes = 0
         self.evictions = 0
         self.inserted_bytes = 0
-        # phase-B logs (log mode): same shapes as the list version
-        self.miss_log: list[tuple[int, int, int]] = []
-        self.insert_log: list[tuple[int, int, int]] = []
-        self.evict_log: list[tuple[int, int, int]] = []
-        self.split_log: list[tuple[int, list, "list | None"]] = []
-        self._req_records: dict[int, list] = {}
 
     # -- introspection -------------------------------------------------------
 
@@ -327,7 +318,7 @@ class FlatIntervalState:
         cap = 64
         while cap < 2 * (m + k):
             cap *= 2
-        for name in ("_fr", "_flo", "_fhi", "_fsrc"):
+        for name in ("_fr", "_flo", "_fhi"):
             old = getattr(self, name)
             na = np.empty(cap, _I64)
             na[:m] = old[h:t][keep]
@@ -336,14 +327,13 @@ class FlatIntervalState:
         self._ft = m
         self._fgen += 1                  # stored FIFO positions renumbered
 
-    def _fifo_push(self, rid: int, lo: int, hi: int, src: int) -> None:
+    def _fifo_push(self, rid: int, lo: int, hi: int) -> None:
         if self._ft == len(self._fr):
             self._fifo_reserve(1)
         t = self._ft
         self._fr[t] = rid
         self._flo[t] = lo
         self._fhi[t] = hi
-        self._fsrc[t] = src
         self._ft = t + 1
 
     def _r_compact(self) -> None:
@@ -649,40 +639,9 @@ class FlatIntervalState:
         # repeat a rid across runs — accumulate, don't assign
         np.add.at(self._live, rids, runs_e - runs_s)
 
-    def _valid_segs(self, rid: int, obj: int, lo: int,
-                    hi: int) -> list[tuple[int, int]]:
-        """Sub-runs of ``[lo, hi)`` still carrying ``rid``, ascending
-        (``obj`` is accepted for list-version API parity; the global key
-        space needs no bucket)."""
-        rn = self._rn
-        i = int(self._re[:rn].searchsorted(lo, side="right"))
-        j = int(self._rs[:rn].searchsorted(hi, side="left"))
-        if i >= j:
-            return []
-        sw = self._rs[i:j]
-        ew = self._re[i:j]
-        m = (self._rr[i:j] == rid) & (ew > sw)
-        s = np.maximum(sw[m], lo)
-        e = np.minimum(ew[m], hi)
-        return list(zip(s.tolist(), e.tolist()))
-
     # -- eviction ------------------------------------------------------------
 
-    def _evict_range(self, s: int, stop: int, rid: int) -> None:
-        """Remove the evicted prefix ``[s, stop)`` (of one recency run
-        carrying ``rid``) from both maps.  The recency run shrinks in
-        place; the size map takes a real splice (it may split)."""
-        rn = self._rn
-        i = int(self._re[:rn].searchsorted(s, side="right"))
-        # [s, stop) is a prefix of the run at i (eviction consumes runs
-        # front-to-back, so the run starts exactly at s)
-        self._rs[i] = stop
-        if stop == self._re[i]:
-            self._rdead += 1
-        self._live[rid] -= stop - s
-        self._splice(True, s, stop, [], [], [])
-
-    def _evict_until(self, size: int, t_now: int) -> None:
+    def _evict_until(self, size: int) -> None:
         """Evict chunks in exact LRU order until ``used + size`` fits —
         the reference's per-chunk loop arithmetically (per victim size
         run, ``ceil(shortfall / chunk_size)`` chunks).  Adaptive: the
@@ -690,10 +649,6 @@ class FlatIntervalState:
         case — a thrash-regime insert frees its need from the head record
         or two), then the batched array scan takes over.  Both consume the
         same LRU prefix, so mixing them is exact."""
-        if self._log:
-            self._plan = None          # per-record pops bypass the plan
-            self._evict_logged(size, t_now)
-            return
         cap = self.capacity
         if self.used + size <= cap:
             return
@@ -1015,7 +970,7 @@ class FlatIntervalState:
             # recover the recency-run index of each victim run: runs are
             # consumed front-to-back, so a live run starts exactly at the
             # victim start and is the first entry ending past it
-            # (end-sortedness; same lookup as _evict_range)
+            # (end-sortedness)
             Fseg = re_live.searchsorted(vs_f, side="right")
             np.add.at(self._live, self._rr[Fseg], -(ve_f - vs_f))
             self._rs[Fseg] = self._re[Fseg]    # tombstone in place
@@ -1054,71 +1009,6 @@ class FlatIntervalState:
         if self._rdead > 64 and self._rdead * 2 > self._rn:
             self._r_compact()
 
-    def _evict_logged(self, size: int, t_now: int) -> None:
-        """Log-mode eviction: the list version's per-record loop (phase B
-        of the sharded driver needs per-call ``evict_log``/``split_log``
-        granularity), with vectorized run gathering."""
-        while self.used + size > self.capacity:
-            if self._fh >= self._ft:
-                raise IndexError("pop from an empty deque")
-            p = self._fh
-            self._fh = p + 1
-            rid = int(self._fr[p])
-            if self._live[rid] <= 0:
-                continue                       # fully stale record
-            lo = int(self._flo[p])
-            hi = int(self._fhi[p])
-            src = int(self._fsrc[p])
-            segs = self._valid_segs(rid, -1, lo, hi)
-            evicted: list[tuple[int, int]] = []
-            stopped_at = None
-            for s, e in segs:
-                stop = s
-                zi = int(self._ze[:self._zn].searchsorted(s, side="right"))
-                while stop < e:
-                    need = self.used + size - self.capacity
-                    if need <= 0:
-                        break
-                    z = int(self._zv[zi])
-                    pe = int(self._ze[zi])
-                    if pe > e:
-                        pe = e
-                    take = min(pe - stop, -(-need // z))
-                    self.used -= take * z
-                    stop += take
-                    if stop == pe:
-                        zi += 1
-                if stop > s:
-                    n_ev = stop - s
-                    self.n_live -= n_ev
-                    self.evictions += n_ev
-                    evicted.append((s, stop))
-                    self.evict_log.append((t_now, s, stop))
-                    self._evict_range(s, stop, rid)
-                if stop < e:
-                    stopped_at = stop
-                    break
-            if stopped_at is not None:
-                self._fh = p                  # re-queue the remainder
-                self._flo[p] = stopped_at
-            if src >= 0 and evicted:
-                if src == t_now:
-                    self.split_log.append((src, evicted, None))
-                else:
-                    remaining: list = []
-                    if stopped_at is not None:
-                        remaining += self._valid_segs(rid, -1, stopped_at,
-                                                      hi)
-                    for rid2, obj2, lo2, hi2 in self._req_records.get(
-                            src, ()):
-                        if rid2 != rid:
-                            remaining += self._valid_segs(rid2, obj2, lo2,
-                                                          hi2)
-                    if remaining:
-                        self.split_log.append((src, evicted, remaining))
-            if stopped_at is not None:
-                return
-
     # -- bulk block APIs (fused block-over-intervals replay) -----------------
 
     def coverage_arrays(self, objs=None) -> tuple[np.ndarray, np.ndarray]:
@@ -1155,16 +1045,14 @@ class FlatIntervalState:
                      r_grp: "list | None" = None) -> None:
         """Bulk-commit one fused replay block (list-of-tuples API parity
         with the list version; see :meth:`commit_block_arrays`)."""
-        za = np.asarray(size_recs, _I64).reshape(-1, 5)
-        ra = np.asarray(recency_recs, _I64).reshape(-1, 4)
+        za = np.asarray(size_recs, _I64).reshape(-1, 4)
+        ra = np.asarray(recency_recs, _I64).reshape(-1, 3)
         self.commit_block_arrays(za[:, 0], za[:, 1], za[:, 2], za[:, 3],
-                                 za[:, 4], ra[:, 0], ra[:, 1], ra[:, 2],
-                                 ra[:, 3],
+                                 ra[:, 1], ra[:, 2],
                                  None if r_grp is None
                                  else np.asarray(r_grp, _I64))
 
-    def commit_block_arrays(self, z_obj, z_lo, z_hi, z_src, z_sz,
-                            r_obj, r_lo, r_hi, r_src,
+    def commit_block_arrays(self, z_obj, z_lo, z_hi, z_sz, r_lo, r_hi,
                             r_grp: "np.ndarray | None" = None) -> None:
         """Bulk-commit one fused replay block from the column arrays the
         engine already computed (same record semantics as the list
@@ -1172,13 +1060,12 @@ class FlatIntervalState:
         bookkeeping in trace order, recency records append FIFO records in
         final-stamp order).  Each map is merged in one batched rebuild.
 
-        ``r_grp`` (non-log mode): contiguous non-decreasing group ids
+        ``r_grp`` (optional): contiguous non-decreasing group ids
         parallel to the recency columns — one group's records (same
         DTN-object group, consecutive final stamps, ascending disjoint key
         runs) are fused under ONE rid and ONE FIFO record spanning
         first-lo..last-hi; see the exactness argument on the list
         version's ``commit_block``."""
-        log = self._log
         kz = len(z_lo)
         p = self._plan
         if p is not None and len(r_lo) and len(p.ks):
@@ -1199,13 +1086,6 @@ class FlatIntervalState:
             for o, b in zip(z_obj.tolist(), z_hi.tolist()):
                 if b > oh.get(o, 0):
                     oh[o] = b
-            if log:
-                ml = self.miss_log
-                il = self.insert_log
-                for rec in zip(z_src.tolist(), z_lo.tolist(),
-                               z_hi.tolist()):
-                    ml.append(rec)
-                    il.append(rec)
             zl = np.asarray(z_lo, _I64)
             zh = np.asarray(z_hi, _I64)
             zz = np.asarray(z_sz, _I64)
@@ -1223,7 +1103,6 @@ class FlatIntervalState:
                 self._z_replace(zl, zh, zz)
         kr = len(r_lo)
         if kr:
-            rr_ = self._req_records
             if r_grp is not None:
                 gh_mask = np.empty(kr, bool)
                 gh_mask[0] = True
@@ -1236,27 +1115,19 @@ class FlatIntervalState:
                 # set live counts immediately, so no bulk reserve is needed)
                 if r_grp is None:
                     self._fifo_reserve(kr)
-                    for o, a, b, s_ in zip(r_obj.tolist(), r_lo.tolist(),
-                                           r_hi.tolist(), r_src.tolist()):
+                    for a, b in zip(r_lo.tolist(), r_hi.tolist()):
                         rid = self._new_rid()
-                        self._fifo_push(rid, a, b, s_)
-                        if log and s_ >= 0:
-                            rr_.setdefault(s_, []).append((rid, o, a, b))
+                        self._fifo_push(rid, a, b)
                         self._splice(False, a, b, (a,), (b,), (rid,))
                     return
                 self._fifo_reserve(G)
                 lo_l = r_lo.tolist()
                 hi_l = r_hi.tolist()
-                src_l = r_src.tolist()
                 for x in range(G):
                     h = int(gh[x])
                     t_ = int(gt[x])
                     rid = self._new_rid()
-                    src_g = src_l[h] if h == t_ else -1
-                    self._fifo_push(rid, lo_l[h], hi_l[t_], src_g)
-                    if log and src_g >= 0:
-                        rr_.setdefault(src_g, []).append(
-                            (rid, int(r_obj[h]), lo_l[h], hi_l[h]))
+                    self._fifo_push(rid, lo_l[h], hi_l[t_])
                     for y in range(h, t_ + 1):
                         self._splice(False, lo_l[y], hi_l[y],
                                      (lo_l[y],), (hi_l[y],), (rid,))
@@ -1267,8 +1138,7 @@ class FlatIntervalState:
                 self._live_reserve(self._next_rid)
                 rids_rec = np.arange(rid0, rid0 + kr, dtype=_I64)
                 rids_run = rids_rec
-                f_lo, f_hi, f_src = r_lo, r_hi, r_src
-                f_obj = r_obj
+                f_lo, f_hi = r_lo, r_hi
                 G = kr
             else:
                 rid0 = self._next_rid
@@ -1278,21 +1148,12 @@ class FlatIntervalState:
                 rids_run = rid0 + (np.cumsum(gh_mask) - 1)
                 f_lo = r_lo[gh]
                 f_hi = r_hi[gt]
-                f_src = np.where(gh == gt, r_src[gh], -1)
-                f_obj = r_obj[gh]
             self._fifo_reserve(G)
             t = self._ft
             self._fr[t:t + G] = rids_rec
             self._flo[t:t + G] = f_lo
             self._fhi[t:t + G] = f_hi
-            self._fsrc[t:t + G] = f_src
             self._ft = t + G
-            if log:
-                for rid, o, a, b, s_ in zip(rids_rec.tolist(),
-                                            f_obj.tolist(), f_lo.tolist(),
-                                            f_hi.tolist(), f_src.tolist()):
-                    if s_ >= 0:
-                        rr_.setdefault(s_, []).append((rid, o, a, b))
             rl = np.asarray(r_lo, _I64)
             rh = np.asarray(r_hi, _I64)
             if not (rl[1:] >= rl[:-1]).all():
@@ -1335,13 +1196,13 @@ class FlatIntervalState:
                     # newest record, fully live: re-touching is a no-op
                     return nh, ()
                 rid = self._new_rid()
-                self._fifo_push(rid, lo, hi, -1)
+                self._fifo_push(rid, lo, hi)
                 self._live[old] -= nh
                 self._live[rid] = nh
                 self._rr[i] = rid
                 return nh, ()
             rid = self._new_rid()
-            self._fifo_push(rid, lo, hi, -1)
+            self._fifo_push(rid, lo, hi)
             self._splice(False, lo, hi, [lo], [hi], [rid])
             return nh, ()
         j = int(rs[:rn].searchsorted(hi, side="left"))
@@ -1385,7 +1246,7 @@ class FlatIntervalState:
             h_r: list = []
             for a, b in hit_runs:
                 rid = self._new_rid()
-                self._fifo_push(rid, a, b, -1)
+                self._fifo_push(rid, a, b)
                 h_s.append(a)
                 h_e.append(b)
                 h_r.append(rid)
@@ -1420,8 +1281,7 @@ class FlatIntervalState:
                 out.append((a, b))
         return out
 
-    def insert_runs(self, obj: int, runs: list, size: int,
-                    req_pos: int) -> None:
+    def insert_runs(self, obj: int, runs: list, size: int) -> None:
         """Insert absent chunk runs (ascending) with reference ``insert``
         semantics (oversize skip, chunk-by-chunk evict-ahead)."""
         if not runs or size > self.capacity:
@@ -1431,54 +1291,42 @@ class FlatIntervalState:
         if runs[-1][1] > oh.get(obj, 0):
             oh[obj] = runs[-1][1]
         if self.used + nm * size <= self.capacity:
-            log = self._log
             for a, b in runs:
                 rid = self._new_rid()
-                self._fifo_push(rid, a, b, req_pos)
-                if log:
-                    self.insert_log.append((req_pos, a, b))
-                    self._req_records.setdefault(req_pos, []).append(
-                        (rid, obj, a, b))
+                self._fifo_push(rid, a, b)
                 self._splice(False, a, b, [a], [b], [rid])
                 self._splice(True, a, b, [a], [b], [size])
             self.used += nm * size
             self.n_live += nm
             self.inserted_bytes += nm * size
             return
-        self._insert_with_evict(obj, runs, size, req_pos)
+        self._insert_with_evict(obj, runs, size)
 
-    def serve(self, req_pos: int, obj: int, lo: int, hi: int,
-              size: int) -> int:
-        """Serve one request, inserting every miss in ascending chunk
-        order (the sharded driver's optimistic phase A)."""
+    def serve(self, obj: int, lo: int, hi: int, size: int) -> int:
+        """Serve one request with every miss inserted in ascending chunk
+        order — the reference's static serve when no chunk comes from a
+        peer.  Returns the hit count."""
         nh, miss_runs = self.lookup_touch(obj, lo, hi, size)
         if miss_runs:
-            if self._log:
-                ml = self.miss_log
-                for a, b in miss_runs:
-                    ml.append((req_pos, a, b))
-            self.insert_runs(obj, miss_runs, size, req_pos)
+            self.insert_runs(obj, miss_runs, size)
         return nh
 
-    def _insert_with_evict(self, obj: int, miss_runs: list, size: int,
-                           req_pos: int) -> None:
-        log = self._log
+    def _insert_with_evict(self, obj: int, miss_runs: list,
+                           size: int) -> None:
         nm = sum(b - a for a, b in miss_runs)
-        if not log and nm * size <= self.capacity:
+        if nm * size <= self.capacity:
             # churn-tail fast path (the degenerate scalar serves): ONE
             # batched eviction for the whole insert volume, then one splice
             # pair per run — exact because LRU prefix consumption is
             # monotone (evicting for the per-chunk cumulative needs in
             # sequence lands on the same final prefix with the same final
             # split arithmetic), and no chunk of this insert can become
-            # its own victim when the volume fits capacity.  Log mode keeps
-            # the reference's per-chunk evict-ahead so the evict/split logs
-            # record each intermediate split for the phase-B audit.
+            # its own victim when the volume fits capacity.
             if self.used + nm * size > self.capacity:
-                self._evict_until(nm * size, req_pos)
+                self._evict_until(nm * size)
             for a, b in miss_runs:
                 rid = self._new_rid()
-                self._fifo_push(rid, a, b, req_pos)
+                self._fifo_push(rid, a, b)
                 self._splice(False, a, b, [a], [b], [rid])
                 self._splice(True, a, b, [a], [b], [size])
             self.used += nm * size
@@ -1492,16 +1340,12 @@ class FlatIntervalState:
             j = a
             while j < b:
                 if self.used + size > self.capacity:
-                    self._evict_until(size, req_pos)
+                    self._evict_until(size)
                 cnt = min(b - j, (self.capacity - self.used) // size)
                 rid = self._new_rid()
                 self._splice(False, j, j + cnt, [j], [j + cnt], [rid])
                 self._splice(True, j, j + cnt, [j], [j + cnt], [size])
-                self._fifo_push(rid, j, j + cnt, req_pos)
-                if log:
-                    self.insert_log.append((req_pos, j, j + cnt))
-                    self._req_records.setdefault(req_pos, []).append(
-                        (rid, obj, j, j + cnt))
+                self._fifo_push(rid, j, j + cnt)
                 self.used += cnt * size
                 self.n_live += cnt
                 self.inserted_bytes += cnt * size

@@ -69,10 +69,10 @@ class SimConfig:
     counter-equivalence contract meaningful; see
     ``tests/test_engine_equivalence.py`` and ``docs/ARCHITECTURE.md``).
 
-    Fields are grouped as: cache layer (policy/budget/chunking), WAN and
-    origin service model (paper §V-A1), and engine execution knobs
-    (``batched_prediction``, ``interval_shards``) that change *how* a
-    result is computed but never *what* it is.
+    Fields describe the deployment: cache layer (policy/budget/chunking)
+    and the WAN and origin service model (paper §V-A1).  The one exception
+    is ``interval_flat_state``, an interval-engine execution option that
+    never changes results.
     """
 
     cache_policy: str = "lru"
@@ -92,26 +92,6 @@ class SimConfig:
     # target utilization at regular traffic.
     origin_latency_s: float = 2.0
     bandwidth_gbps: np.ndarray | None = None
-    # Vector engine only: pre-compute the whole-trace prediction plan through
-    # the prefetcher's batched planner (two-phase HPM: vmapped ARIMA bank +
-    # memoized rules) instead of calling ``observe`` per request.  Emits the
-    # identical op stream (tests/test_hpm_equivalence.py); set False to force
-    # the online path, e.g. for benchmarking the prediction layer itself.
-    # The reference simulator always replays online.
-    batched_prediction: bool = True
-    # Interval engine only.  ``None`` (default): the replay planner picks
-    # between the sequential interval sweep (fine-chunking regime) and the
-    # inherited vector block replay.  ``1``: pin the sequential sweep.
-    # ``N > 1``: the sharded multi-DTN driver — N worker processes (capped
-    # at CPU count and active-DTN count) sweep disjoint DTN subsets in
-    # parallel; exact counters are preserved via the phase-B presence-
-    # timeline reconciliation and eviction-split audit (falling back to
-    # the sweep when an audit check is order-sensitive).  Sharding pays
-    # off on balanced traces / many-core hosts; OOI-like skew (~68% of
-    # requests on one DTN) caps its parallel gain.  Other engines ignore
-    # this knob.  The workers are forked: never set ``N > 1`` in a process
-    # that holds a TPU (e.g. after any hpm run there).
-    interval_shards: int | None = None
     # Interval engine only, execution knob (never changes results): back
     # the fused block replay's caches with the flat array-backed
     # ``FlatIntervalState`` (batched commit/evict kernels) instead of the
@@ -674,16 +654,18 @@ def run_strategy(
 
     - ``"vector"`` (default): the array-backed batch-replay engine
       (:mod:`repro.core.engine`) — same results, 1-2 orders of magnitude
-      faster on the serving hot path.  For prefetchers that support it
-      (hpm), prediction runs in batch mode: the whole-trace op stream is
-      planned up front through the vmapped ARIMA bank
-      (``config.batched_prediction``, on by default).
-    - ``"interval"``: interval-algebra presence tracking plus the sharded
-      multi-DTN phase-A driver (``config.interval_shards`` workers) for
-      static LRU serving (cache_only); dynamic strategies and LFU delegate
-      to the vector machinery.  The fastest engine on serving-bound traces
-      and the only one whose per-request cost is independent of the chunk
-      resolution.
+      faster on the serving hot path.  Prefetchers with a window planner
+      (hpm) are planned a window at a time through the vmapped ARIMA bank;
+      the others (md1, md2) predict online.
+    - ``"interval"``: interval-algebra presence tracking for static LRU
+      serving (cache_only), by fused block replay or, at fine chunking,
+      the interval sweep; dynamic strategies and LFU delegate to the vector
+      machinery.  The fastest engine on serving-bound traces and the only
+      one whose per-request cost is independent of the chunk resolution.
+
+    ``requests`` is a list (one window; the result keeps per-request
+    ``outcomes``) or a :class:`repro.core.trace.StreamingRequestSource`
+    (replayed window by window; outcomes are folded into ``aggregate``).
     - ``"reference"``: the per-chunk dict/heap :class:`VDCSimulator` above —
       the readable semantic baseline the other engines are verified
       against, always predicting online via per-request ``observe``.

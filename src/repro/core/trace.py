@@ -514,14 +514,32 @@ class StreamingRequestSource:
     @classmethod
     def from_requests(cls, requests: Sequence[Request],
                       window: int = 65536) -> "StreamingRequestSource":
-        """Wrap an in-memory trace (tests: stream==materialize audits)."""
-        if requests:
-            lo = min(r.tr_start for r in requests)
-            hi = max(r.tr_end for r in requests)
-        else:
-            lo = hi = 0.0
-        return cls(lambda: iter(requests), window=window,
-                   n_requests=len(requests), tr_bounds=(lo, hi))
+        """Wrap an in-memory trace; the engines replay a list as the
+        single window of such a source.  Windows are slices of one
+        :class:`RequestList`, so its memoized column transpose is sliced,
+        never rebuilt."""
+        if not isinstance(requests, RequestList):
+            requests = RequestList(requests)
+        arr = requests_to_arrays(requests)
+        bounds = ((float(arr.tr_start.min()), float(arr.tr_end.max()))
+                  if len(arr) else (0.0, 0.0))
+        return _SlicedSource(requests, window, bounds)
+
+
+class _SlicedSource(StreamingRequestSource):
+    """:meth:`StreamingRequestSource.from_requests`'s source over an
+    in-memory trace."""
+
+    def __init__(self, requests: RequestList, window: int,
+                 tr_bounds: tuple[float, float]):
+        super().__init__(lambda: iter(requests), window=window,
+                         n_requests=len(requests), tr_bounds=tr_bounds)
+        self._requests = requests
+
+    def windows(self) -> "Iterator[RequestList]":
+        rl, w = self._requests, self.window
+        for i in range(0, len(rl), w):
+            yield rl[i:i + w]
 
 
 class StreamingTraceSynthesizer:

@@ -274,7 +274,7 @@ class TestStreaming:
 # ------------------------------------------------- peer-fetch resolution
 
 
-from repro.core.delivery import (PeerFetchRange, coalesce_peer_fetches,
+from repro.core.delivery import (PeerFetchRange, coalesce_peer_ranges,
                                  select_peer_sources)
 
 
@@ -296,14 +296,20 @@ def _ref_peer_choice(bw_to_dtn, holders):
 
 
 def _chunk_decisions(draw_rows):
-    """Normalize drawn rows into the (req_pos, keys, src) arrays the replay
-    engines hand to ``coalesce_peer_fetches``: req_pos non-decreasing, keys
+    """Normalize drawn rows into per-chunk (req_pos, keys, src) decisions in
+    the order the replay engines emit runs: req_pos non-decreasing, keys
     strictly increasing within a request, src per chunk."""
     rows = sorted(set(draw_rows))
     req = np.array([r for r, _, _ in rows], np.int64)
     keys = np.array([k for _, k, _ in rows], np.int64)
     src = np.array([s for _, _, s in rows], np.int64)
     return req, keys, src
+
+
+def _coalesce_chunks(req, keys, src, dtn):
+    """``coalesce_peer_ranges`` over one-chunk runs into one DTN."""
+    return coalesce_peer_ranges(req, np.full(len(req), dtn), src, keys,
+                                keys + 1)
 
 
 class TestPeerResolution:
@@ -313,7 +319,7 @@ class TestPeerResolution:
     @settings(max_examples=80, deadline=None)
     def test_property_coalesce_covers_and_merges(self, rows):
         req, keys, src = _chunk_decisions(rows)
-        out = coalesce_peer_fetches(req, keys, src, dtn=4)
+        out = _coalesce_chunks(req, keys, src, dtn=4)
         # exact cover: every input chunk in exactly one range, nothing else
         got = sorted((r.req_pos, k, r.src)
                      for r in out for k in range(r.key_lo, r.key_hi))
@@ -330,7 +336,7 @@ class TestPeerResolution:
     @settings(max_examples=80, deadline=None)
     def test_property_coalesce_idempotent(self, rows):
         req, keys, src = _chunk_decisions(rows)
-        out = coalesce_peer_fetches(req, keys, src, dtn=2)
+        out = _coalesce_chunks(req, keys, src, dtn=2)
         # re-expanding the ranges and re-coalescing is a fixed point
         req2 = np.array([r.req_pos for r in out
                          for _ in range(r.key_lo, r.key_hi)], np.int64)
@@ -338,7 +344,7 @@ class TestPeerResolution:
                           for k in range(r.key_lo, r.key_hi)], np.int64)
         src2 = np.array([r.src for r in out
                          for _ in range(r.key_lo, r.key_hi)], np.int64)
-        assert coalesce_peer_fetches(req2, keys2, src2, dtn=2) == out
+        assert _coalesce_chunks(req2, keys2, src2, dtn=2) == out
 
     @given(st.integers(0, 2**64 - 1))
     @settings(max_examples=120, deadline=None)

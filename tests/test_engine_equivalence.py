@@ -58,8 +58,7 @@ def _int_counters(res):
     }
 
 
-_ENGINE_ONLY_KNOBS = ("interval_shards", "batched_prediction",
-                      "interval_flat_state")
+_ENGINE_ONLY_KNOBS = ("interval_flat_state",)
 
 
 def _ref_run(trace, splits, strategy, **cfg_kw):
@@ -109,28 +108,14 @@ def test_engines_agree(trace, strategy, splits):
 
 
 @pytest.mark.parametrize("trace", ["ooi", "gage"])
-@pytest.mark.parametrize("shards", [1, 2])
-def test_interval_engine_agrees(trace, shards, splits):
-    """The interval engine's static serving path — the sequential global
-    sweep (shards=1) and the optimistic sharded driver (shards=2, forked
-    phase-A workers + timeline peer resolution + split audit) — against the
-    reference, on the ISSUE-named seeded OOI and GAGE traces."""
-    ref, ivl = _run_both(trace, splits, "cache_only", engine="interval",
-                         interval_shards=shards)
+@pytest.mark.parametrize("route", ["sweep", "fused"])
+def test_interval_engine_agrees(trace, route, splits, interval_route):
+    """The interval engine's static serving path — the interval sweep and
+    the fused block replay, each pinned — against the reference, on the
+    seeded OOI and GAGE traces."""
+    with interval_route(route):
+        ref, ivl = _run_both(trace, splits, "cache_only", engine="interval")
     _assert_equivalent(ref, ivl)
-
-
-@pytest.mark.parametrize("trace", ["ooi", "gage"])
-def test_sharded_driver_deterministic_shards3(trace, splits):
-    """Phase A packs DTN subsequences into shards largest-first with the
-    ``(-total, dtn_id)`` tie-break, so a repeated run at
-    ``interval_shards=3`` must reproduce counters bit-for-bit (and match
-    the reference) — no set/dict iteration order may leak into packing."""
-    ref, a = _run_both(trace, splits, "cache_only", engine="interval",
-                       interval_shards=3)
-    _, b = _run_both(trace, splits, "cache_only", engine="interval",
-                     interval_shards=3)
-    assert _int_counters(a) == _int_counters(b) == _int_counters(ref)
 
 
 @pytest.mark.parametrize("trace", ["ooi", "gage"])
@@ -146,11 +131,13 @@ def test_flat_and_list_state_agree(trace, splits):
 
 
 @pytest.mark.parametrize("trace", ["ooi", "gage"])
-def test_interval_engine_agrees_under_eviction_pressure(trace, splits):
-    """Thrash regime: interval eviction must split/consume records in the
-    reference's exact per-chunk LRU order."""
-    ref, ivl = _run_both(trace, splits, "cache_only", engine="interval",
-                         cache_bytes=16 << 20, interval_shards=1)
+def test_interval_engine_agrees_under_eviction_pressure(trace, splits,
+                                                        interval_route):
+    """Thrash regime: the interval sweep's eviction must split/consume
+    records in the reference's exact per-chunk LRU order."""
+    with interval_route("sweep"):
+        ref, ivl = _run_both(trace, splits, "cache_only", engine="interval",
+                             cache_bytes=16 << 20)
     _assert_equivalent(ref, ivl)
 
 
@@ -172,32 +159,37 @@ def test_engines_agree_under_eviction_pressure(trace, splits):
     _assert_equivalent(ref, vec)
 
 
-@pytest.mark.parametrize("engine,shards", [
-    ("vector", None), ("interval", None), ("interval", 1), ("interval", 2)])
-def test_engines_agree_thrash_regime(engine, shards, splits):
+@pytest.mark.parametrize("engine,route", [
+    ("vector", None), ("interval", None), ("interval", "sweep")])
+def test_engines_agree_thrash_regime(engine, route, splits, interval_route):
     """Seeded pin of the benchmark's 8 GB eviction-thrash row (ISSUE 6): a
     cache roughly the size of the hot working set, so most inserts evict.
     At the equivalence-suite trace scale (0.035) the same regime lands at
     24 MB; the assertion guard keeps the pin honest if trace calibration
-    drifts.  Routes pinned: vector block replay, the interval engine's
-    auto planner, the sequential sweep, and the sharded driver."""
-    ref, new = _run_both("ooi", splits, "cache_only", engine=engine,
-                         cache_bytes=24 << 20, interval_shards=shards)
+    drifts.  Routes: vector block replay, the interval engine's own choice
+    (the fused block replay here), and the pinned interval sweep."""
+    with interval_route(route):
+        ref, new = _run_both("ooi", splits, "cache_only", engine=engine,
+                             cache_bytes=24 << 20)
     ev = sum(s.evictions for s in ref.cache_stats.values())
     miss = sum(s.misses for s in ref.cache_stats.values())
     assert ev > 0.5 * miss, "not a thrash regime — recalibrate the pin"
     _assert_equivalent(ref, new)
 
 
-@pytest.mark.parametrize("engine,shards", [
-    ("vector", None), ("interval", None), ("interval", 1), ("interval", 2)])
-def test_engines_agree_fine_chunking_60s(engine, shards, splits):
-    """Seeded pin of the benchmark's 60 s fine-chunking row (ISSUE 6):
-    sub-minute chunks push mean chunks/request past the interval planner's
-    sweep threshold, and at 1 GB the regime also evicts heavily — the
-    sweep's insert-with-evict machinery runs under genuine pressure."""
-    ref, new = _run_both("ooi", splits, "cache_only", engine=engine,
-                         chunk_seconds=60.0, interval_shards=shards)
+@pytest.mark.parametrize("engine,route", [
+    ("vector", None), ("interval", None), ("interval", "sweep")])
+def test_engines_agree_fine_chunking_60s(engine, route, splits,
+                                         interval_route):
+    """Seeded pin of the benchmark's 60 s fine-chunking row (ISSUE 6): at
+    1 GB the regime evicts heavily, so the insert-with-evict machinery runs
+    under genuine pressure.  On this reduced trace a request spans about
+    19 chunks on average, under ``SWEEP_MIN_CHUNKS_PER_REQ``, so the
+    interval engine's own choice is the fused block replay; the interval
+    sweep is pinned as the third route."""
+    with interval_route(route):
+        ref, new = _run_both("ooi", splits, "cache_only", engine=engine,
+                             chunk_seconds=60.0)
     miss = sum(s.misses for s in ref.cache_stats.values())
     assert miss > 10 * len(splits["ooi"][1]), \
         "not a fine-chunking regime — recalibrate the pin"
@@ -252,12 +244,11 @@ def _peer_heavy_trace() -> tuple[ObjectGrid, RequestList]:
 
 
 def _order_sensitivity_trace() -> tuple[ObjectGrid, RequestList]:
-    """Minimal reproduction of the sharded driver's peer-vs-origin insert
-    ORDER hazard: the EU request at t=102 misses two runs — [0,5) from the
-    origin and [10,15) from the NA peer — and the eviction at t=103
-    consumes exactly one whole insert record.  The reference queues the
-    peer record first, optimistic phase A queues ascending; the split
-    audit must catch this and fall back to the exact sweep."""
+    """Minimal peer-vs-origin insert ORDER hazard: the EU request at t=102
+    misses two runs — [0,5) from the origin and [10,15) from the NA peer —
+    and the eviction at t=103 consumes exactly one whole insert record.
+    The reference queues the peer record first; an engine that inserted
+    in ascending key order would evict the wrong record."""
     return ObjectGrid(2, 2), RequestList([
         Request(100.0, 1, 0, 10.0, 15.0, 5 * _U, 0),   # NA caches [10,15)
         Request(101.0, 2, 0, 5.0, 10.0, 5 * _U, 2),    # EU caches [5,10)
@@ -267,35 +258,35 @@ def _order_sensitivity_trace() -> tuple[ObjectGrid, RequestList]:
     ])
 
 
-def _run_cross_dtn(grid, trace, engine, cache_bytes, shards=None,
-                   chunk_seconds=3600.0):
+def _run_cross_dtn(grid, trace, engine, cache_bytes, chunk_seconds=3600.0):
     cfg = SimConfig(stream_rate_bytes_per_s=8e3, cache_bytes=cache_bytes,
-                    chunk_seconds=chunk_seconds,
-                    interval_shards=shards).calibrate_origin(trace)
+                    chunk_seconds=chunk_seconds).calibrate_origin(trace)
     return run_strategy("cache_only", trace, grid, cfg, None, engine=engine)
 
 
-@pytest.mark.parametrize("shards", [1, 2])
-def test_engines_agree_with_real_peer_traffic(shards):
+@pytest.mark.parametrize("route", ["sweep", "fused"])
+def test_engines_agree_with_real_peer_traffic(route, interval_route):
     grid, trace = _peer_heavy_trace()
     ref = _run_cross_dtn(grid, trace, "reference", 128 * _U)
     assert sum(o.peer_bytes for o in ref.outcomes) > 0   # not vacuous
-    for engine, kw in (("vector", {}), ("interval", {"shards": shards})):
-        new = _run_cross_dtn(grid, trace, engine, 128 * _U, **kw)
-        _assert_equivalent(ref, new)
+    new = _run_cross_dtn(grid, trace, "vector", 128 * _U)
+    _assert_equivalent(ref, new)
+    with interval_route(route):
+        new = _run_cross_dtn(grid, trace, "interval", 128 * _U)
+    _assert_equivalent(ref, new)
 
 
-@pytest.mark.parametrize("shards", [1, 2])
-def test_interval_engine_honors_disabled_peer_cache(shards):
-    """Regression: the sharded driver's phase B used to resolve peer
-    fetches even with ``enable_peer_cache=False``, mis-splitting peer vs
-    origin bytes."""
+@pytest.mark.parametrize("route", ["sweep", "fused"])
+def test_interval_engine_honors_disabled_peer_cache(route, interval_route):
+    """With ``enable_peer_cache=False`` no interval route may resolve peer
+    fetches (a former multi-process route once did, mis-splitting peer vs
+    origin bytes)."""
     grid, trace = _peer_heavy_trace()
     cfg = SimConfig(stream_rate_bytes_per_s=8e3, cache_bytes=128 * _U,
-                    enable_peer_cache=False,
-                    interval_shards=shards).calibrate_origin(trace)
-    ivl = run_strategy("cache_only", trace, grid, cfg, None,
-                       engine="interval")
+                    enable_peer_cache=False).calibrate_origin(trace)
+    with interval_route(route):
+        ivl = run_strategy("cache_only", trace, grid, cfg, None,
+                           engine="interval")
     assert sum(o.peer_bytes for o in ivl.outcomes) == 0
     cfg = SimConfig(stream_rate_bytes_per_s=8e3, cache_bytes=128 * _U,
                     enable_peer_cache=False).calibrate_origin(trace)
@@ -304,37 +295,38 @@ def test_interval_engine_honors_disabled_peer_cache(shards):
     _assert_equivalent(ref, ivl)
 
 
-@pytest.mark.parametrize("shards", [1, 2])
-def test_sharded_audit_catches_cross_record_insert_order(shards):
-    """Regression: an eviction that consumed a WHOLE insert record while a
-    sibling record of the same request survived used to slip past the
-    split audit (it only checked within-record order), silently diverging
-    from the reference under interval_shards>1."""
+@pytest.mark.parametrize("route", ["sweep", "fused"])
+def test_sharded_audit_catches_cross_record_insert_order(route,
+                                                         interval_route):
+    """Regression: an eviction that consumes a WHOLE insert record while a
+    sibling record of the same request survives must take the peer record
+    first, as the reference does, on every interval route (a former
+    multi-process route once diverged here)."""
     grid, trace = _order_sensitivity_trace()
     ref = _run_cross_dtn(grid, trace, "reference", 15 * _U,
                          chunk_seconds=1.0)
     assert sum(o.peer_bytes for o in ref.outcomes) > 0
-    ivl = _run_cross_dtn(grid, trace, "interval", 15 * _U, shards=shards,
-                         chunk_seconds=1.0)
+    with interval_route(route):
+        ivl = _run_cross_dtn(grid, trace, "interval", 15 * _U,
+                             chunk_seconds=1.0)
     _assert_equivalent(ref, ivl)
     vec = _run_cross_dtn(grid, trace, "vector", 15 * _U, chunk_seconds=1.0)
     _assert_equivalent(ref, vec)
 
 
-def test_interval_engine_reports_peer_fetch_ranges():
+def test_interval_engine_reports_peer_fetch_ranges(interval_route):
     """The interval sweep exposes its accepted peer transfers as coalesced
     ranges whose chunk totals match the peer_bytes outcome column."""
     from repro.core.delivery import make_prefetcher
     from repro.core.engine import IntervalVDCSimulator
-    import dataclasses as _dc
 
     grid, trace = _peer_heavy_trace()
     cfg = SimConfig(stream_rate_bytes_per_s=8e3, cache_bytes=128 * _U,
-                    interval_shards=1,
                     enable_placement=False).calibrate_origin(trace)
     pf = make_prefetcher("cache_only", grid, None)
     sim = IntervalVDCSimulator(grid, pf, cfg, use_cache=True)
-    res = sim.run(trace, name="cache_only")
+    with interval_route("sweep"):
+        res = sim.run(trace, name="cache_only")
     assert sim.last_peer_fetches                          # not vacuous
     by_req: dict[int, int] = {}
     for r in sim.last_peer_fetches:

@@ -139,44 +139,48 @@ def gen_scenario(rng: random.Random):
     return grid, trace, cfg_kw
 
 
-def check_strategy(strategy, grid, trace, cfg_kw, window=None):
+def check_strategy(strategy, grid, trace, cfg_kw, pin, window=None):
     """Replay one scenario through every engine (and, for static LRU
-    serving, through every interval route) and compare counters — then do
-    it again through the windowed streaming source (``window`` requests at
-    a time; randomized by the sweeps), which must match bit-for-bit."""
+    serving, through every interval route, pinned with ``pin`` — the
+    ``interval_route`` fixture) and compare counters — then do it again
+    through the windowed streaming source (``window`` requests at a time;
+    randomized by the sweeps), which must match bit-for-bit."""
     # ``interval_flat_state`` defaults to True, so the plain interval run
     # already sweeps the flat array-backed store; the False run pins the
     # Python-list reference store to the same counters (PR 7 bugfix bar)
-    runs = [("vector", {}), ("interval", {}),
-            ("interval", {"interval_flat_state": False})]
+    runs = [("vector", {}, None), ("interval", {}, None),
+            ("interval", {"interval_flat_state": False}, None)]
     if strategy == "cache_only" and cfg_kw["cache_policy"] == "lru":
-        # pin all three interval routes: auto planner (fused block replay /
-        # sweep), pinned sequential sweep, sharded driver + split audit
-        runs += [("interval", {"interval_shards": 1}),
-                 ("interval", {"interval_shards": 2})]
+        # pin both interval routes besides the engine's own choice: the
+        # interval sweep and the fused block replay
+        runs += [("interval", {}, "sweep"), ("interval", {}, "fused")]
     ref = run_strategy(strategy, trace, grid,
                        SimConfig(**cfg_kw), None, engine="reference")
     want = _int_counters(ref)
-    for engine, extra in runs:
-        res = run_strategy(strategy, trace, grid,
-                           SimConfig(**cfg_kw, **extra), None, engine=engine)
+    for engine, extra, route in runs:
+        with pin(route):
+            res = run_strategy(strategy, trace, grid,
+                               SimConfig(**cfg_kw, **extra), None,
+                               engine=engine)
         got = _int_counters(res)
         assert got == want, (
-            f"{engine} engine ({extra or 'default'}) diverged from the "
-            f"reference under {strategy}: {got} != {want}")
+            f"{engine} engine ({extra or route or 'default'}) diverged from "
+            f"the reference under {strategy}: {got} != {want}")
     w = window or max(1, len(trace) // 3)
     src = StreamingRequestSource.from_requests(trace, window=w)
-    for engine, extra in [("reference", {})] + runs:
-        res = run_strategy(strategy, src, grid,
-                           SimConfig(**cfg_kw, **extra), None, engine=engine)
+    for engine, extra, route in [("reference", {}, None)] + runs:
+        with pin(route):
+            res = run_strategy(strategy, src, grid,
+                               SimConfig(**cfg_kw, **extra), None,
+                               engine=engine)
         got = _int_counters(res)
         assert got == want, (
-            f"{engine} engine ({extra or 'default'}) streamed with "
+            f"{engine} engine ({extra or route or 'default'}) streamed with "
             f"window={w} diverged from the reference under {strategy}: "
             f"{got} != {want}")
 
 
-def _sweep(strategy: str, n_examples: int) -> None:
+def _sweep(strategy: str, n_examples: int, pin) -> None:
     for i in range(n_examples):
         rng = random.Random((FUZZ_SEED, strategy, i).__repr__())
         grid, trace, cfg_kw = gen_scenario(rng)
@@ -184,7 +188,7 @@ def _sweep(strategy: str, n_examples: int) -> None:
         # identically; width 1 forces a window per request
         window = rng.choice((1, 2, 3, 5, 9, 17))
         try:
-            check_strategy(strategy, grid, trace, cfg_kw, window=window)
+            check_strategy(strategy, grid, trace, cfg_kw, pin, window=window)
         except AssertionError as e:
             raise AssertionError(
                 f"scenario {i} (seed base {FUZZ_SEED}) of strategy "
@@ -197,21 +201,21 @@ def _sweep(strategy: str, n_examples: int) -> None:
 
 
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_fuzz_engines_agree_fast(strategy):
-    _sweep(strategy, FAST_EXAMPLES)
+def test_fuzz_engines_agree_fast(strategy, interval_route):
+    _sweep(strategy, FAST_EXAMPLES, interval_route)
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_fuzz_engines_agree_deep(strategy):
-    _sweep(strategy, DEEP_EXAMPLES)
+def test_fuzz_engines_agree_deep(strategy, interval_route):
+    _sweep(strategy, DEEP_EXAMPLES, interval_route)
 
 
 THRASH_EXAMPLES = 10
 
 
 @pytest.mark.parametrize("strategy", ("cache_only", "md1", "hpm"))
-def test_fuzz_thrash_regime(strategy):
+def test_fuzz_thrash_regime(strategy, interval_route):
     """Eviction-thrash sweep: the cache is pinned to a few requests' worth
     of bytes so nearly every fused block runs the speculative eviction
     planner's full lifecycle — plan, truncate, incremental re-plan,
@@ -224,7 +228,8 @@ def test_fuzz_thrash_regime(strategy):
         cfg_kw["cache_bytes"] = rng.choice([128 << 10, 256 << 10, _U])
         window = rng.choice((1, 3, 7, 17))
         try:
-            check_strategy(strategy, grid, trace, cfg_kw, window=window)
+            check_strategy(strategy, grid, trace, cfg_kw, interval_route,
+                           window=window)
         except AssertionError as e:
             raise AssertionError(
                 f"thrash scenario {i} (seed base {FUZZ_SEED}) of strategy "
@@ -278,17 +283,18 @@ def gen_phased_scenario(rng: random.Random):
 
 
 @pytest.mark.parametrize("strategy", ("cache_only", "md1"))
-def test_fuzz_phased_eviction(strategy):
+def test_fuzz_phased_eviction(strategy, interval_route):
     """Derandomized phased-eviction sweep: capacity drawn below the trace's
     insert volume so blocks are forced to span 2-8x the cache.  LRU is
-    pinned, so the cache_only leg also sweeps the sharded
-    (``interval_shards=2``) phased route via :func:`check_strategy`."""
+    pinned, so the cache_only leg also sweeps both pinned interval routes
+    via :func:`check_strategy`."""
     for i in range(PHASED_EXAMPLES):
         rng = random.Random((FUZZ_SEED, "phased", strategy, i).__repr__())
         grid, trace, cfg_kw = gen_phased_scenario(rng)
         window = rng.choice((5, 9, 17))
         try:
-            check_strategy(strategy, grid, trace, cfg_kw, window=window)
+            check_strategy(strategy, grid, trace, cfg_kw, interval_route,
+                           window=window)
         except AssertionError as e:
             raise AssertionError(
                 f"phased scenario {i} (seed base {FUZZ_SEED}) of strategy "
@@ -339,7 +345,7 @@ def test_phased_block_spans_capacity():
         assert res.inblock_victims >= 4, (engine, res.inblock_victims)
 
 
-def test_inblock_victim_rereference():
+def test_inblock_victim_rereference(interval_route):
     """In-block-victim re-reference regression: the first range's keys are
     re-touched by the LAST request of the block, so at every earlier phase
     boundary they are ineligible victims (the suffix-blocked plan must
@@ -347,7 +353,8 @@ def test_inblock_victim_rereference():
     them and serves the re-touch as a miss.  Exact counter equality across
     every engine and route is the bar."""
     grid, trace = _churn_trace(13, rereference=True)
-    check_strategy("cache_only", grid, trace, _CHURN_CFG, window=5)
+    check_strategy("cache_only", grid, trace, _CHURN_CFG, interval_route,
+                   window=5)
 
 
 # ---------------------------------------------------------------------------
@@ -369,10 +376,12 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=DEEP_EXAMPLES, derandomize=True, deadline=None,
               suppress_health_check=[HealthCheck.too_slow])
     @given(sub_seed=st.integers(0, 2**48))
-    def test_fuzz_engines_agree_hypothesis(strategy, sub_seed):
+    def test_fuzz_engines_agree_hypothesis(strategy, sub_seed,
+                                           interval_route):
         """Same grammar, hypothesis-chosen seeds (with shrinking to the
         smallest failing sub-seed on divergence)."""
         rng = random.Random((FUZZ_SEED, strategy, sub_seed).__repr__())
         grid, trace, cfg_kw = gen_scenario(rng)
         window = rng.choice((1, 2, 3, 5, 9, 17))
-        check_strategy(strategy, grid, trace, cfg_kw, window=window)
+        check_strategy(strategy, grid, trace, cfg_kw, interval_route,
+                       window=window)

@@ -154,7 +154,8 @@ def test_seeded_trace_counts_decisions(trace):
     cut = int(len(tr) * 0.3)
     txs = build_rule_transactions(tr[:cut])
     telemetry.reset()
-    BatchedHPMPlanner(HybridPrefetcher(rule_transactions=txs)).plan(tr[cut:])
+    BatchedHPMPlanner(HybridPrefetcher(rule_transactions=txs)).plan_window(
+        tr[cut:])
     counts = telemetry.counters()
     assert counts["gap_decisions"] > 100
     assert counts["gap_exact"] <= counts["gap_decisions"] // 1000

@@ -187,7 +187,7 @@ def _assert_plan_equals_observe(test_reqs, train_reqs):
     txs = build_rule_transactions(train_reqs) if train_reqs else None
     online = HybridPrefetcher(rule_transactions=txs)
     planner = BatchedHPMPlanner(HybridPrefetcher(rule_transactions=txs))
-    planned = planner.plan(test_reqs)
+    planned = planner.plan_window(test_reqs)
     n_ops = 0
     for i, r in enumerate(test_reqs):
         observed = online.observe(r)
@@ -266,7 +266,7 @@ def test_plan_window_split_matches_whole_plan_seeded():
     test_reqs, train_reqs = tr[cut:], tr[:cut]
     txs = build_rule_transactions(train_reqs)
     whole = BatchedHPMPlanner(
-        HybridPrefetcher(rule_transactions=txs)).plan(test_reqs)
+        HybridPrefetcher(rule_transactions=txs)).plan_window(test_reqs)
     mid = len(test_reqs) // 3
     split = _windowed_ops(test_reqs, [mid], txs)
     assert [list(ops) for ops in whole] == [list(ops) for ops in split]

@@ -6,7 +6,7 @@ eviction order and every counter — while holding presence, sizes and
 recency as sorted disjoint ``[start, end)`` intervals.  These tests pin the
 named edge cases (zero-length/adjacent ranges, merge-on-insert, eviction
 splitting an interval, the full-cache boundary) plus the engine-side
-interval utilities (presence timelines, peer-fetch ranges).  Engine-level
+interval utilities (peer-fetch ranges, peer selection).  Engine-level
 counter equality on seeded traces lives in ``test_engine_equivalence.py``.
 """
 import random
@@ -15,9 +15,8 @@ import numpy as np
 import pytest
 
 from repro.core.cache import IntervalLRUState, LRUCache
-from repro.core.delivery import (PeerFetchRange, coalesce_peer_fetches,
+from repro.core.delivery import (PeerFetchRange, coalesce_peer_ranges,
                                  select_peer_sources)
-from repro.core.engine import PresenceTimeline
 from repro.core.interval_store import FlatIntervalState
 
 
@@ -47,7 +46,7 @@ def keys_of(state: IntervalLRUState) -> list[int]:
 
 def test_zero_length_range_is_a_noop():
     st = IntervalLRUState(100)
-    assert st.serve(0, 0, 5, 5, 10) == 0
+    assert st.serve(0, 5, 5, 10) == 0
     assert st.lookup_touch(0, 7, 7, 10) == (0, ())
     st.check_invariants()
     assert st.intervals() == []
@@ -56,8 +55,8 @@ def test_zero_length_range_is_a_noop():
 
 def test_adjacent_ranges_merge_on_insert():
     st = IntervalLRUState(1000)
-    st.serve(0, 0, 0, 3, 1)          # miss-insert [0, 3)
-    st.serve(1, 0, 3, 6, 1)          # adjacent miss-insert [3, 6)
+    st.serve(0, 0, 3, 1)          # miss-insert [0, 3)
+    st.serve(0, 3, 6, 1)          # adjacent miss-insert [3, 6)
     st.check_invariants()
     assert st.intervals() == [(0, 6)]            # merged coverage
     assert st.coverage_runs(0, 0, 10) == [(0, 6)]
@@ -68,10 +67,10 @@ def test_adjacent_ranges_merge_on_insert():
 
 def test_merge_on_insert_fills_interior_gap():
     st = IntervalLRUState(1000)
-    st.serve(0, 0, 0, 2, 1)
-    st.serve(1, 0, 4, 6, 1)
+    st.serve(0, 0, 2, 1)
+    st.serve(0, 4, 6, 1)
     assert st.intervals() == [(0, 2), (4, 6)]
-    st.serve(2, 0, 2, 4, 1)          # fills the hole
+    st.serve(0, 2, 4, 1)          # fills the hole
     st.check_invariants()
     assert st.intervals() == [(0, 6)]
 
@@ -82,9 +81,9 @@ def test_eviction_splits_an_interval():
     # stored interval, exactly like the per-chunk reference
     ref = LRUCache(4)
     st = IntervalLRUState(4)
-    assert ref_serve(ref, 0, 4, 1) == st.serve(0, 0, 0, 4, 1) == 0
-    assert ref_serve(ref, 1, 3, 1) == st.serve(1, 0, 1, 3, 1) == 2
-    assert ref_serve(ref, 10, 12, 1) == st.serve(2, 0, 10, 12, 1) == 0
+    assert ref_serve(ref, 0, 4, 1) == st.serve(0, 0, 4, 1) == 0
+    assert ref_serve(ref, 1, 3, 1) == st.serve(0, 1, 3, 1) == 2
+    assert ref_serve(ref, 10, 12, 1) == st.serve(0, 10, 12, 1) == 0
     st.check_invariants()
     assert keys_of(st) == sorted(ref._od.keys()) == [1, 2, 10, 11]
     assert st.intervals() == [(1, 3), (10, 12)]  # [0,4) was split
@@ -96,10 +95,10 @@ def test_full_cache_boundary():
     ref = LRUCache(6)
     st = IntervalLRUState(6)
     ref_serve(ref, 0, 3, 2)
-    st.serve(0, 0, 0, 3, 2)
+    st.serve(0, 0, 3, 2)
     assert st.used == st.capacity == 6
     ref_serve(ref, 5, 6, 2)
-    st.serve(1, 0, 5, 6, 2)
+    st.serve(0, 5, 6, 2)
     st.check_invariants()
     assert st.used == 6
     assert st.evictions == ref.stats.evictions == 1
@@ -112,9 +111,9 @@ def test_oversized_chunk_is_skipped_not_evicted():
     ref = LRUCache(10)
     st = IntervalLRUState(10)
     ref_serve(ref, 0, 5, 2)
-    st.serve(0, 0, 0, 5, 2)
+    st.serve(0, 0, 5, 2)
     ref_serve(ref, 7, 8, 11)
-    st.serve(1, 0, 7, 8, 11)
+    st.serve(0, 7, 8, 11)
     st.check_invariants()
     assert st.evictions == ref.stats.evictions == 0
     assert keys_of(st) == sorted(ref._od.keys())
@@ -128,7 +127,7 @@ def test_eviction_inside_one_request_self_evicts_in_order():
     ref = LRUCache(3)
     st = IntervalLRUState(3)
     ref_serve(ref, 0, 5, 1)
-    st.serve(0, 0, 0, 5, 1)
+    st.serve(0, 0, 5, 1)
     st.check_invariants()
     assert keys_of(st) == sorted(ref._od.keys()) == [2, 3, 4]
     assert st.evictions == ref.stats.evictions == 2
@@ -145,13 +144,12 @@ def test_matches_reference_randomized(seed):
     cap = rng.choice([23, 37, 50, 200, 1000])
     ref = LRUCache(cap)
     st = IntervalLRUState(cap)
-    for step in range(120):
+    for _ in range(120):
         obj = rng.randrange(2)
         lo = obj * 1000 + rng.randrange(0, 60)
         hi = lo + rng.randrange(0, 12)
         size = rng.choice([1, 2, 5, 13, 60])
-        assert ref_serve(ref, lo, hi, size) == st.serve(step, obj, lo, hi,
-                                                        size)
+        assert ref_serve(ref, lo, hi, size) == st.serve(obj, lo, hi, size)
         st.check_invariants()
         assert keys_of(st) == sorted(ref._od.keys())
         s = ref.stats
@@ -179,8 +177,8 @@ def test_partitioned_insert_order_matches_reference(seed):
     rng = random.Random(10_000 + seed)
     cap = rng.choice([23, 37, 50])
     ref = LRUCache(cap)
-    st = IntervalLRUState(cap, log_events=False)
-    for step in range(150):
+    st = IntervalLRUState(cap)
+    for _ in range(150):
         obj = rng.randrange(2)
         lo = obj * 1000 + rng.randrange(0, 40)
         hi = lo + rng.randrange(0, 10)
@@ -200,8 +198,8 @@ def test_partitioned_insert_order_matches_reference(seed):
         for k in (k for k in missing if k not in peer):
             ref.insert(k, size)
         assert nh_r == nh_i
-        st.insert_runs(obj, _runs_from(peer), size, step)
-        st.insert_runs(obj, _runs_from(set(all_miss) - peer), size, step)
+        st.insert_runs(obj, _runs_from(peer), size)
+        st.insert_runs(obj, _runs_from(set(all_miss) - peer), size)
         st.check_invariants()
         assert keys_of(st) == sorted(ref._od.keys())
         assert st.evictions == ref.stats.evictions
@@ -212,34 +210,12 @@ def test_partitioned_insert_order_matches_reference(seed):
 # ---------------------------------------------------------------------------
 
 
-def test_presence_timeline_strict_interval_membership():
-    ins = np.array([[2, 10, 13], [7, 20, 21]], np.int64)   # (t, lo, hi)
-    ev = np.array([[5, 10, 11], [9, 20, 21]], np.int64)
-    tl = PresenceTimeline(ins, ev, horizon=20)
-    keys = np.array([10, 10, 10, 11, 12, 20, 20], np.int64)
-    qs = np.array([2, 3, 6, 6, 1, 8, 9], np.int64)
-    got = tl.query(keys, qs).tolist()
-    #   chunk 10: inserted @2 evicted @5 -> present only strictly inside
-    #   chunk 11, 12: inserted @2, never evicted
-    #   chunk 20: inserted @7 evicted @9
-    assert got == [False, True, False, True, False, True, False]
-
-
-def test_presence_timeline_same_position_insert_evict_invisible():
-    # a chunk inserted and self-evicted while serving the same request must
-    # never be visible to peers
-    ins = np.array([[4, 5, 6]], np.int64)
-    ev = np.array([[4, 5, 6]], np.int64)
-    tl = PresenceTimeline(ins, ev, horizon=10)
-    assert not tl.query(np.array([5]), np.array([4])).any()
-    assert not tl.query(np.array([5]), np.array([6])).any()
-
-
 def test_coalesce_peer_fetches_groups_ranges():
     req = np.array([3, 3, 3, 3, 7], np.int64)
     keys = np.array([10, 11, 12, 20, 10], np.int64)
     src = np.array([2, 2, 4, 2, 2], np.int64)
-    got = coalesce_peer_fetches(req, keys, src, dtn=1)
+    got = coalesce_peer_ranges(req, np.ones(len(req), np.int64), src, keys,
+                               keys + 1)
     assert got == [
         PeerFetchRange(3, 1, 2, 10, 12),
         PeerFetchRange(3, 1, 4, 12, 13),
@@ -277,19 +253,19 @@ def test_snapshot_fresh_after_eviction(cls):
     size-map mutation — insert, eviction, block commit — must invalidate
     it.  A stale memo here would silently corrupt every later fused block's
     start-of-block presence snapshot."""
-    st = cls(4, log_events=False)
-    st.serve(0, 0, 0, 4, 1)                   # fill [0, 4) exactly
+    st = cls(4)
+    st.serve(0, 0, 4, 1)                   # fill [0, 4) exactly
     ss, ee = st.coverage_arrays()             # populates the memo
     assert (ss.tolist(), ee.tolist()) == ([0], [4])
     # inserting [10, 12) evicts the two oldest chunks of the first record
-    st.serve(1, 0, 10, 12, 1)
+    st.serve(0, 10, 12, 1)
     ss, ee = st.coverage_arrays()
     assert (ss.tolist(), ee.tolist()) == ([2, 10], [4, 12])
     assert st.evictions == 2 and st.used == 4
     # a fused block commit must invalidate too (the commit path bypasses
     # insert_runs); evict room first so the commit is in-contract
-    st._evict_until(1, 2)
-    st.commit_block([(0, 20, 21, 2, 1)], [(0, 20, 21, 2)])
+    st._evict_until(1)
+    st.commit_block([(0, 20, 21, 1)], [(0, 20, 21)])
     ss, ee = st.coverage_arrays()
     assert (ss.tolist(), ee.tolist()) == ([3, 10, 20], [4, 12, 21])
     st.check_invariants()
@@ -302,8 +278,8 @@ def test_plan_evict_clean_clamps_mid_segment(cls):
     ``max_need`` — never the overshot run total.  The fused-replay call
     site only ever compares the result against the shortfall, so the clamp
     is contract-neutral there (see ``plan_evict_clean``'s docstring)."""
-    st = cls(1000, log_events=False)
-    st.serve(0, 0, 0, 10, 4)                  # one 10-chunk size-4 record
+    st = cls(1000)
+    st.serve(0, 0, 10, 4)                  # one 10-chunk size-4 record
     # need lands mid-run (10 bytes = 2.5 chunks into a 40-byte run)
     assert st.plan_evict_clean(10, [], []) == 10
     # a blocked run inside the segment truncates the scan at its start
@@ -317,23 +293,20 @@ def _state_digest(st):
                 miss_bytes=st.miss_bytes, evictions=st.evictions,
                 inserted_bytes=st.inserted_bytes, used=st.used,
                 n_live=st.n_live, iv=st.intervals(),
-                miss_log=list(st.miss_log), insert_log=list(st.insert_log),
-                evict_log=list(st.evict_log), split_log=list(st.split_log),
                 obj_hi=dict(st.obj_hi))
 
 
-@pytest.mark.parametrize("seed", range(12))
-@pytest.mark.parametrize("log", [True, False])
-def test_flat_matches_list_randomized(seed, log):
+@pytest.mark.parametrize("seed", range(24))
+def test_flat_matches_list_randomized(seed):
     """Differential fuzz of FlatIntervalState against IntervalLRUState
     across the full behavioral API: serve, lookup_touch, coverage queries,
-    fused block commits, eviction plans — digests (counters, intervals,
-    event logs) must agree at every checkpoint."""
+    fused block commits, eviction plans — digests (counters, intervals)
+    must agree at every checkpoint."""
     span = 1 << 20
-    rng = random.Random((20260808, "flat-vs-list", seed, log).__repr__())
+    rng = random.Random((20260808, "flat-vs-list", seed).__repr__())
     cap = rng.choice([200, 1000, 5000])
-    a = IntervalLRUState(cap, log_events=log)
-    b = FlatIntervalState(cap, log_events=log)
+    a = IntervalLRUState(cap)
+    b = FlatIntervalState(cap)
     sizes: dict = {}
     for step in range(130):
         op = rng.random()
@@ -342,8 +315,8 @@ def test_flat_matches_list_randomized(seed, log):
         lo = obj * span + rng.randrange(300)
         hi = lo + rng.randrange(1, 60)
         if op < 0.55:
-            assert a.serve(step, obj, lo, hi, size) == \
-                b.serve(step, obj, lo, hi, size)
+            assert a.serve(obj, lo, hi, size) == \
+                b.serve(obj, lo, hi, size)
         elif op < 0.72:
             ra = a.lookup_touch(obj, lo, hi, size)
             rb = b.lookup_touch(obj, lo, hi, size)
@@ -367,11 +340,11 @@ def test_flat_matches_list_randomized(seed, log):
                     j = i
                     while j + 1 < len(run) and run[j + 1] == run[j] + 1:
                         j += 1
-                    recs_z.append((obj, run[i], run[j] + 1, step, size))
-                    recs_r.append((obj, run[i], run[j] + 1, step))
+                    recs_z.append((obj, run[i], run[j] + 1, size))
+                    recs_r.append((obj, run[i], run[j] + 1))
                     held.update(range(run[i], run[j] + 1))
                     i = j + 1
-            tot = sum((e0 - s0) * sz for _, s0, e0, _, sz in recs_z)
+            tot = sum((e0 - s0) * sz for _, s0, e0, sz in recs_z)
             if recs_z and a.used + tot <= cap:
                 a.commit_block(recs_z, recs_r)
                 b.commit_block(recs_z, recs_r)
@@ -389,32 +362,31 @@ def test_flat_matches_list_randomized(seed, log):
 
 
 @pytest.mark.parametrize("cls", _STATES)
-@pytest.mark.parametrize("log", [True, False])
-def test_plan_invalidated_by_mid_block_commit(cls, log):
+def test_plan_invalidated_by_mid_block_commit(cls):
     """A commit whose recency record lands inside a speculative plan's
     victim set must drop the cached plan: the re-stamped run is now MRU,
     so consuming the stale plan would evict the wrong victims.  Proven by
     transparency — the planned state must stay digest-identical to a twin
     that never planned (planning is a pure, cached scan)."""
-    planned = cls(100, log_events=log)
-    twin = cls(100, log_events=log)
+    planned = cls(100)
+    twin = cls(100)
     for st in (planned, twin):
-        st.serve(0, 0, 0, 30, 1)          # record A — the oldest victim
-        st.serve(1, 0, 100, 130, 1)       # record B
-        st.serve(2, 0, 200, 230, 1)       # record C; used = 90 of 100
+        st.serve(0, 0, 30, 1)          # record A — the oldest victim
+        st.serve(0, 100, 130, 1)       # record B
+        st.serve(0, 200, 230, 1)       # record C; used = 90 of 100
     clean = planned.plan_evict_clean(40, [], [])
     assert clean == 40 and planned._plan is not None
     # mid-block commit: insert D (fits the remaining room) and re-stamp
     # [5, 25) — strictly inside planned victim A — to recency t=3
-    recs_z = [(0, 300, 310, 3, 1)]
-    recs_r = [(0, 5, 25, 3), (0, 300, 310, 3)]
+    recs_z = [(0, 300, 310, 1)]
+    recs_r = [(0, 5, 25), (0, 300, 310)]
     for st in (planned, twin):
         st.commit_block(recs_z, recs_r)
     assert planned._plan is None          # the guard must have fired
     # eviction pressure: 40 inserted bytes evict the A remnants (10) and
     # B (30) in true LRU order; a stale plan would have taken all of A
     for st in (planned, twin):
-        st.serve(4, 0, 400, 440, 1)
+        st.serve(0, 400, 440, 1)
     assert planned.coverage_runs(0, 0, 30) == [(5, 25)]
     assert _state_digest(planned) == _state_digest(twin)
     planned.check_invariants()
@@ -422,8 +394,7 @@ def test_plan_invalidated_by_mid_block_commit(cls, log):
 
 
 @pytest.mark.parametrize("cls", _STATES)
-@pytest.mark.parametrize("log", [True, False])
-def test_plan_invalidated_by_inblock_victim_restab(cls, log):
+def test_plan_invalidated_by_inblock_victim_restab(cls):
     """Phased-replay hazard (ISSUE 10): a boundary plan may list records
     that were committed by EARLIER PHASES of the same block — in-block
     victims, entered via ``commit_block`` rather than ``serve``.  A later
@@ -431,12 +402,12 @@ def test_plan_invalidated_by_inblock_victim_restab(cls, log):
     exactly like the pre-block record-stab rule above; the stab guard must
     not depend on how the victim record was created.  Transparency twin
     proves the whole sequence."""
-    planned = cls(100, log_events=log)
-    twin = cls(100, log_events=log)
+    planned = cls(100)
+    twin = cls(100)
     # phase-1-style commit: A, B, C enter through the fused commit path
     # (in-block records), not through serve
-    recs_z1 = [(0, 0, 30, 0, 1), (0, 100, 130, 1, 1), (0, 200, 230, 2, 1)]
-    recs_r1 = [(0, 0, 30, 0), (0, 100, 130, 1), (0, 200, 230, 2)]
+    recs_z1 = [(0, 0, 30, 1), (0, 100, 130, 1), (0, 200, 230, 1)]
+    recs_r1 = [(0, 0, 30), (0, 100, 130), (0, 200, 230)]
     for st in (planned, twin):
         st.commit_block(recs_z1, recs_r1)
     assert planned.used == 90
@@ -445,14 +416,14 @@ def test_plan_invalidated_by_inblock_victim_restab(cls, log):
     clean = planned.plan_evict_clean(40, [], [])
     assert clean == 40 and planned._plan is not None
     # phase-2 commit re-touches [5, 25) inside in-block victim A
-    recs_z2 = [(0, 300, 310, 3, 1)]
-    recs_r2 = [(0, 5, 25, 3), (0, 300, 310, 3)]
+    recs_z2 = [(0, 300, 310, 1)]
+    recs_r2 = [(0, 5, 25), (0, 300, 310)]
     for st in (planned, twin):
         st.commit_block(recs_z2, recs_r2)
     assert planned._plan is None          # stab guard fired on in-block A
     # pressure: the A remnants (10) + B (30) must go in true LRU order
     for st in (planned, twin):
-        st.serve(4, 0, 400, 440, 1)
+        st.serve(0, 400, 440, 1)
     assert planned.coverage_runs(0, 0, 30) == [(5, 25)]
     assert _state_digest(planned) == _state_digest(twin)
     planned.check_invariants()
@@ -469,11 +440,11 @@ def test_flat_plan_fgen_stale_early_return_is_safe():
     plans — so pin the safety argument: plan, force a real compaction via
     recency churn on non-victims, re-query through the stale-fgen early
     return, then evict, all digest-identical to a plan-free twin."""
-    planned = FlatIntervalState(10_000, log_events=False)
-    twin = FlatIntervalState(10_000, log_events=False)
+    planned = FlatIntervalState(10_000)
+    twin = FlatIntervalState(10_000)
     for st in (planned, twin):
         for k in range(40):
-            st.serve(k, 0, 10 * k, 10 * k + 10, 1)   # 400 chunks, no evict
+            st.serve(0, 10 * k, 10 * k + 10, 1)   # 400 chunks, no evict
     assert planned.plan_evict_clean(50, [], []) == 50
     p = planned._plan
     assert p is not None
@@ -498,27 +469,26 @@ def test_flat_plan_fgen_stale_early_return_is_safe():
     # real pressure: _evict_until sees p.fgen != self._fgen, drops the
     # stale plan and walks fresh — digests must stay identical
     for st in (planned, twin):
-        st.serve(9000, 0, 1 << 20, (1 << 20) + 9_700, 1)
+        st.serve(0, 1 << 20, (1 << 20) + 9_700, 1)
     assert _state_digest(planned) == _state_digest(twin)
     planned.check_invariants()
     twin.check_invariants()
 
 
 @pytest.mark.parametrize("cls", _STATES)
-@pytest.mark.parametrize("log", [True, False])
-@pytest.mark.parametrize("seed", range(4))
-def test_plan_is_semantically_inert_randomized(cls, log, seed):
+@pytest.mark.parametrize("seed", range(8))
+def test_plan_is_semantically_inert_randomized(cls, seed):
     """Seeded transparency fuzz: interleave speculative plans (on one state
     only) with serves, lookups and fused commits whose recency records may
     land on present runs — including planned victims.  The planning state
     must remain digest-identical to a plan-free twin at every checkpoint,
     whatever mix of plan reuse, extension and invalidation occurs."""
     span = 1 << 20
-    rng = random.Random((20260808, "plan-inert", seed, log,
+    rng = random.Random((20260808, "plan-inert", seed,
                          cls.__name__).__repr__())
     cap = rng.choice([150, 600])
-    planned = cls(cap, log_events=log)
-    twin = cls(cap, log_events=log)
+    planned = cls(cap)
+    twin = cls(cap)
     sizes: dict = {}
     for step in range(120):
         op = rng.random()
@@ -527,8 +497,8 @@ def test_plan_is_semantically_inert_randomized(cls, log, seed):
         lo = obj * span + rng.randrange(250)
         hi = lo + rng.randrange(1, 40)
         if op < 0.45:
-            assert planned.serve(step, obj, lo, hi, size) == \
-                twin.serve(step, obj, lo, hi, size)
+            assert planned.serve(obj, lo, hi, size) == \
+                twin.serve(obj, lo, hi, size)
         elif op < 0.65:
             # speculative plan on one state only (pure scan, cached)
             bl = sorted(rng.sample(range(obj * span, obj * span + 300), 2))
@@ -551,8 +521,8 @@ def test_plan_is_semantically_inert_randomized(cls, log, seed):
                     j = i
                     while j + 1 < len(run) and run[j + 1] == run[j] + 1:
                         j += 1
-                    recs_z.append((obj, run[i], run[j] + 1, step, size))
-                    recs_r.append((obj, run[i], run[j] + 1, step))
+                    recs_z.append((obj, run[i], run[j] + 1, size))
+                    recs_r.append((obj, run[i], run[j] + 1))
                     held.update(range(run[i], run[j] + 1))
                     i = j + 1
             iv = planned.intervals()
@@ -560,8 +530,8 @@ def test_plan_is_semantically_inert_randomized(cls, log, seed):
                 s, e = iv[rng.randrange(len(iv))]
                 s2 = rng.randrange(s, e)
                 e2 = rng.randrange(s2 + 1, e + 1)
-                recs_r.append((s2 // span, s2, e2, step))
-            tot = sum((e0 - s0) * sz for _, s0, e0, _, sz in recs_z)
+                recs_r.append((s2 // span, s2, e2))
+            tot = sum((e0 - s0) * sz for _, s0, e0, sz in recs_z)
             if recs_r and planned.used + tot <= cap:
                 planned.commit_block(recs_z, recs_r)
                 twin.commit_block(recs_z, recs_r)

@@ -9,17 +9,25 @@ submits — with float aggregates equal to summation-order rounding.  This
 module pins that contract across all three engines x all five strategies on
 the seeded OOI/GAGE traces (plus the interval engine's execution knobs), the
 synthesizer's determinism/prefix guarantees, and the bounded-memory property
-the tentpole is for (slow-marked).
+the tentpole is for (slow-marked).  A materialized list is itself replayed as
+the single window of such a source; that contract and the interval engine's
+route choice are pinned here too.
 """
 import dataclasses
 import itertools
 import resource
 
+import numpy as np
 import pytest
 
 from repro.core import SimConfig, make_trace, run_strategy
-from repro.core.trace import (GAGE_PROFILE, OOI_PROFILE, RequestList,
-                              StreamingRequestSource,
+from repro.core.cache import IntervalLRUState
+from repro.core.delivery import make_prefetcher
+from repro.core.engine import IntervalVDCSimulator
+from repro.core.interval_store import FlatIntervalState
+from repro.core.simulator import OutcomeAggregate, SimResult
+from repro.core.trace import (GAGE_PROFILE, OOI_PROFILE, ObjectGrid,
+                              Request, RequestList, StreamingRequestSource,
                               StreamingTraceSynthesizer)
 
 PROFILES = {"ooi": OOI_PROFILE, "gage": GAGE_PROFILE}
@@ -111,27 +119,28 @@ def test_streaming_equals_materialized(trace, strategy, engine, splits):
     _assert_float_close(mat, stream)
 
 
-@pytest.mark.parametrize("cfg_kw", [
-    {"interval_shards": 2},
-    {"interval_flat_state": True},
-    {"interval_flat_state": False},
-    {"chunk_seconds": 60.0},        # fine chunking: the sweep regime
+@pytest.mark.parametrize("cfg_kw,route", [
+    ({"interval_flat_state": True}, None),
+    ({"interval_flat_state": False}, None),
+    ({"chunk_seconds": 60.0}, "sweep"),     # fine chunking, sweep pinned
     # eviction-pressure legs: a cache far below the working set keeps the
     # fused path planning/truncating across window boundaries, so the
     # speculative eviction plan's reuse-and-invalidate lifecycle is pinned
     # under the streaming==materialized contract for both state layouts
-    {"cache_bytes": 1 << 22},
-    {"cache_bytes": 1 << 22, "interval_flat_state": False},
-], ids=["shards2", "flat_on", "flat_off", "sweep", "thrash_flat",
-        "thrash_list"])
-def test_streaming_interval_knobs(cfg_kw, splits):
+    ({"cache_bytes": 1 << 22}, None),
+    ({"cache_bytes": 1 << 22, "interval_flat_state": False}, None),
+], ids=["flat_on", "flat_off", "sweep", "thrash_flat", "thrash_list"])
+def test_streaming_interval_knobs(cfg_kw, route, splits, interval_route):
     trace, strategy = "ooi", "cache_only"
-    mat = _mat_run(trace, splits, strategy, "interval", **cfg_kw)
     train, test = splits[trace]
     src = StreamingRequestSource.from_requests(test, window=WINDOW)
-    stream = run_strategy(strategy, src, PROFILES[trace].grid,
-                          _cfg(trace, test, **cfg_kw), train,
-                          engine="interval")
+    with interval_route(route):
+        mat = run_strategy(strategy, test, PROFILES[trace].grid,
+                           _cfg(trace, test, **cfg_kw), train,
+                           engine="interval")
+        stream = run_strategy(strategy, src, PROFILES[trace].grid,
+                              _cfg(trace, test, **cfg_kw), train,
+                              engine="interval")
     assert _int_counters(mat) == _int_counters(stream)
     _assert_float_close(mat, stream)
 
@@ -147,6 +156,71 @@ def test_window_width_one_and_whole_trace(splits):
         stream = run_strategy(strategy, src, PROFILES[trace].grid,
                               _cfg(trace, test), train, engine="vector")
         assert _int_counters(mat) == _int_counters(stream), w
+
+
+#: outcome fields in the order the engines fold their columns
+#: (``OutcomeAggregate.add_columns``)
+_FOLDED_FIELDS = ("bytes", "latency", "transfer_time", "local_bytes",
+                  "prefetched_bytes", "peer_bytes", "origin_bytes",
+                  "peer_time")
+
+
+@pytest.mark.parametrize("engine,strategy", [
+    ("vector", "cache_only"), ("vector", "hpm"), ("vector", "md2"),
+    ("interval", "cache_only")])
+def test_list_equals_one_window_source(engine, strategy, splits,
+                                       monkeypatch):
+    """A list is replayed as the single window of a source: every
+    ``SimResult`` field of the list run equals the one-window source run's,
+    and its per-request ``outcomes`` are exactly the columns that run
+    folds."""
+    trace = "ooi"
+    lst = _mat_run(trace, splits, strategy, engine)
+    folded = []
+    add_columns = OutcomeAggregate.add_columns
+
+    def capture(agg, *cols):
+        folded.append([np.array(c, copy=True) for c in cols])
+        add_columns(agg, *cols)
+
+    monkeypatch.setattr(OutcomeAggregate, "add_columns", capture)
+    train, test = splits[trace]
+    src = StreamingRequestSource.from_requests(test, window=len(test))
+    one = run_strategy(strategy, src, PROFILES[trace].grid,
+                       _cfg(trace, test), train, engine=engine)
+    assert len(folded) == 1
+    assert lst.aggregate is None and list(one.outcomes) == []
+    for f in dataclasses.fields(SimResult):
+        if f.name not in ("outcomes", "aggregate"):
+            assert getattr(lst, f.name) == getattr(one, f.name), f.name
+    assert [o.ts for o in lst.outcomes] == [r.ts for r in test]
+    assert [o.user_id for o in lst.outcomes] == [r.user_id for r in test]
+    for name, col in zip(_FOLDED_FIELDS, folded[0]):
+        np.testing.assert_array_equal(
+            np.array([getattr(o, name) for o in lst.outcomes]), col,
+            err_msg=name)
+
+
+@pytest.mark.parametrize("chunking", ["coarse", "fine"])
+@pytest.mark.parametrize("feed", ["list", "source"])
+def test_interval_route_choice(feed, chunking):
+    """The interval engine picks its static route from the first window's
+    mean chunk positions per request — for a list, the whole trace's: the
+    fused block replay on flat interval states at hourly chunks (8 a
+    request here), the interval sweep on list-backed states at 60 s chunks
+    (480 a request)."""
+    grid = ObjectGrid(2, 2)
+    trace = RequestList(
+        Request(3600.0 * (20 + i), 1 + i % 3, i % 2, 3600.0 * i,
+                3600.0 * (i + 8), 8 << 20, i % 3) for i in range(30))
+    cfg = SimConfig(chunk_seconds=3600.0 if chunking == "coarse" else 60.0,
+                    enable_placement=False).calibrate_origin(trace)
+    sim = IntervalVDCSimulator(grid, make_prefetcher("cache_only", grid),
+                               cfg)
+    sim.run(trace if feed == "list"
+            else StreamingRequestSource.from_requests(trace, window=7))
+    want = FlatIntervalState if chunking == "coarse" else IntervalLRUState
+    assert {type(c) for c in sim.caches.values()} == {want}
 
 
 # ---------------------------------------------------------------------------
