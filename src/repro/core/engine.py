@@ -930,7 +930,7 @@ class VectorVDCSimulator:
         user_dtn = self._user_dtn
         heap_in, push_in = len(heap), _pending_pushes(heap)
         noop_in = self._pref_noop
-        emitted_in = absorbed_in = 0
+        emitted_in = absorbed_in = builds_in = 0
         absorb = subscribe = pushes_until = None
         n_ops = 0 if plan is None else sum(map(len, plan.ops))
         with telemetry.span("vdc.engine.loop") as sp:
@@ -940,6 +940,7 @@ class VectorVDCSimulator:
             if stream_engine is not None:
                 emitted_in = stream_engine.pushes_emitted
                 absorbed_in = stream_engine.requests_absorbed
+                builds_in = stream_engine.dtn_builds
                 absorb = sp.timed("stream_ns", stream_engine.absorb)
                 subscribe = sp.timed("stream_ns", stream_engine.subscribe)
                 pushes_until = sp.timed("stream_ns",
@@ -985,14 +986,16 @@ class VectorVDCSimulator:
                     with telemetry.span("vdc.engine.placement"):
                         self._run_placement(now)
                     self._last_placement_ts = now
-            n_pushes = n_absorbed = 0
+            n_pushes = n_absorbed = n_builds = 0
             if stream_engine is not None:
                 n_pushes = stream_engine.pushes_emitted - emitted_in
                 n_absorbed = stream_engine.requests_absorbed - absorbed_in
+                n_builds = stream_engine.dtn_builds - builds_in
             applied = heap_in + n_ops + n_pushes - len(heap)
             pushes = push_in + n_pushes - _pending_pushes(heap)
             sp.count(requests=n_req, prefetch_events=applied - pushes,
                      push_events=pushes, absorbed=n_absorbed,
+                     dtn_builds=n_builds,
                      prefetch_noop=self._pref_noop - noop_in)
 
     # -- serving -------------------------------------------------------------

@@ -17,14 +17,16 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Callable, Iterable
+from typing import NamedTuple
 
 from repro.core.trace import Request
 
 
-@dataclasses.dataclass(frozen=True)
-class StreamPush:
-    """A push of new data for a stream to a set of client DTNs."""
+class StreamPush(NamedTuple):
+    """A push of new data for a stream to a set of client DTNs.  A named
+    tuple, because a replay of real-time users builds one per publication
+    interval and stream (close to a million an OOI job), and a tuple is
+    far cheaper to construct than a frozen dataclass."""
 
     ts: float
     obj: int
@@ -41,6 +43,9 @@ class _Subscription:
         default_factory=lambda: collections.defaultdict(set)
     )  # dtn -> user ids
     last_push_end: float = 0.0
+    # sorted DTNs with at least one subscriber; rebuilt by the registry
+    # whenever ``subscribers`` changes, read by every push
+    dtns: tuple[int, ...] = ()
 
 
 class StreamingEngine:
@@ -50,6 +55,7 @@ class StreamingEngine:
         self.subs: dict[int, _Subscription] = {}     # obj -> subscription
         self.pushes_emitted = 0
         self.requests_absorbed = 0
+        self.dtn_builds = 0
         # earliest time any stream could be due; lets the per-request poll in
         # the simulators return without scanning every subscription
         self._next_due = float("inf")
@@ -63,6 +69,7 @@ class StreamingEngine:
         else:
             sub.period = min(sub.period, period)   # fastest subscriber wins
         sub.subscribers[dtn].add(user_id)
+        self._build_dtns(sub)
         self._next_due = min(self._next_due, sub.last_push_end + sub.period)
 
     def unsubscribe(self, user_id: int, obj: int) -> None:
@@ -71,6 +78,11 @@ class StreamingEngine:
             return
         for users in sub.subscribers.values():
             users.discard(user_id)
+        self._build_dtns(sub)
+
+    def _build_dtns(self, sub: _Subscription) -> None:
+        sub.dtns = tuple(sorted(d for d, u in sub.subscribers.items() if u))
+        self.dtn_builds += 1
 
     def is_subscribed(self, user_id: int, obj: int) -> bool:
         sub = self.subs.get(obj)
@@ -92,17 +104,24 @@ class StreamingEngine:
             # event between publication intervals
             return []
         out: list[StreamPush] = []
+        emit = out.append
         nxt = float("inf")
         for sub in self.subs.values():
-            dtns = tuple(sorted(d for d, u in sub.subscribers.items() if u))
+            dtns = sub.dtns
             if not dtns:
+                # no subscriber left: the stream waits, and catches up once
+                # it has one again
                 continue
-            while sub.last_push_end + sub.period <= now:
-                start = sub.last_push_end
-                end = start + sub.period
-                out.append(StreamPush(end, sub.obj, start, end, dtns))
-                sub.last_push_end = end
-                self.pushes_emitted += 1
-            nxt = min(nxt, sub.last_push_end + sub.period)
+            obj, period = sub.obj, sub.period
+            start = sub.last_push_end
+            end = start + period
+            while end <= now:
+                emit(StreamPush(end, obj, start, end, dtns))
+                start = end
+                end = start + period
+            sub.last_push_end = start
+            if end < nxt:
+                nxt = end
+        self.pushes_emitted += len(out)
         self._next_due = nxt
         return out

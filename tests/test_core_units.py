@@ -243,6 +243,48 @@ class TestPlacement:
 
 # ------------------------------------------------------------- streaming
 
+class _ScanEngine:
+    """The registry as it was before subscriptions kept their DTN tuple:
+    every due tick re-sorts the subscribed DTNs of every subscription."""
+
+    def __init__(self):
+        self.subs = {}
+        self._next_due = float("inf")
+
+    def subscribe(self, user_id, dtn, obj, period, now):
+        sub = self.subs.get(obj)
+        if sub is None:
+            sub = self.subs[obj] = {"obj": obj, "period": period,
+                                    "subscribers": {}, "last": now}
+        else:
+            sub["period"] = min(sub["period"], period)
+        sub["subscribers"].setdefault(dtn, set()).add(user_id)
+        self._next_due = min(self._next_due, sub["last"] + sub["period"])
+
+    def unsubscribe(self, user_id, obj):
+        if obj in self.subs:
+            for users in self.subs[obj]["subscribers"].values():
+                users.discard(user_id)
+
+    def pushes_until(self, now):
+        if now < self._next_due:
+            return []
+        out, nxt = [], float("inf")
+        for sub in self.subs.values():
+            dtns = tuple(sorted(d for d, u in sub["subscribers"].items()
+                                if u))
+            if not dtns:
+                continue
+            while sub["last"] + sub["period"] <= now:
+                start = sub["last"]
+                end = start + sub["period"]
+                out.append((end, sub["obj"], start, end, dtns))
+                sub["last"] = end
+            nxt = min(nxt, sub["last"] + sub["period"])
+        self._next_due = nxt
+        return out
+
+
 class TestStreaming:
     def test_absorb_after_subscribe(self):
         eng = StreamingEngine()
@@ -260,6 +302,59 @@ class TestStreaming:
         # 3 intervals elapsed -> 3 pushes, each to BOTH dtns (combined)
         assert len(pushes) == 3
         assert all(p.dtns == (2, 3) for p in pushes)
+
+    @pytest.mark.parametrize("seed", range(8))
+    def test_pushes_match_a_full_scan(self, seed):
+        """Kept DTN tuples emit what re-sorting every subscription at every
+        tick emitted: the same pushes, in the same order, field by field,
+        over random subscribe/unsubscribe/poll sequences."""
+        rng = np.random.default_rng(seed)
+        eng, ref = StreamingEngine(), _ScanEngine()
+        periods = (60.0, 60.0, 59.0, 61.0, 30.5, 300.0)
+        now, builds, emitted = 1000.0 * rng.random(), 0, 0
+
+        def both(name, *args):
+            nonlocal builds
+            getattr(eng, name)(*args)
+            getattr(ref, name)(*args)
+            if name == "subscribe" or args[1] in ref.subs:
+                builds += 1
+
+        def poll():
+            nonlocal emitted
+            got, want = eng.pushes_until(now), ref.pushes_until(now)
+            assert [tuple(p) for p in got] == want
+            assert all(p.dtns == w[4] for p, w in zip(got, want))
+            emitted += len(want)
+
+        for _ in range(400):
+            step = rng.random()
+            if step < 0.35:
+                # several subscriptions created at the same ``now``
+                for _ in range(int(rng.integers(1, 4))):
+                    both("subscribe", int(rng.integers(8)),
+                         int(rng.integers(1, 7)), int(rng.integers(6)),
+                         periods[rng.integers(len(periods))], now)
+            elif step < 0.5:
+                both("unsubscribe", int(rng.integers(8)),
+                     int(rng.integers(7)))
+            elif step < 0.55 and ref.subs:
+                # a subscription loses every subscriber, waits, and catches
+                # up once it has one again
+                obj = list(ref.subs)[rng.integers(len(ref.subs))]
+                for uid in range(8):
+                    both("unsubscribe", uid, obj)
+                assert eng.subs[obj].dtns == ()
+                now += 600.0 * rng.random()
+                poll()
+                both("subscribe", int(rng.integers(8)),
+                     int(rng.integers(1, 7)), obj, 60.0, now)
+            else:
+                now += float(rng.choice([0.0, 1.0, 59.0, 60.0, 250.0]))
+            poll()
+        assert emitted > 0
+        assert eng.pushes_emitted == emitted
+        assert eng.dtn_builds == builds
 
     def test_markov_predictor(self):
         from repro.core.trace import ObjectGrid

@@ -20,6 +20,7 @@ import pytest
 from repro.core import SimConfig, StreamingRequestSource, arima, run_strategy
 from repro.core import telemetry
 from repro.core.engine import VectorVDCSimulator
+from repro.core.streaming import StreamingEngine
 from repro.core.trace import OOI_PROFILE, WEEK, TraceGenerator
 
 WINDOW = 512
@@ -178,11 +179,20 @@ def test_timed_events_match_the_counters(replay, monkeypatch):
             return _fn(sim, *args)
 
         monkeypatch.setattr(VectorVDCSimulator, name, counted)
+    subscribe = StreamingEngine.subscribe
+
+    def counted_subscribe(eng, *args):
+        applied["subscribe"] += 1
+        return subscribe(eng, *args)
+
+    monkeypatch.setattr(StreamingEngine, "subscribe", counted_subscribe)
     telemetry.reset()
     res = replay()
     counts = telemetry.counters()
     assert counts["prefetch_events"] == applied["_apply_prefetch"] > 0
     assert counts["push_events"] == applied["_apply_push"] > 0
+    # nothing unsubscribes in a replay: one DTN tuple built per subscribe
+    assert counts["dtn_builds"] == applied["subscribe"] > 0
     assert counts["requests"] == res.total_requests
 
 
