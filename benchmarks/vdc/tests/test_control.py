@@ -1,13 +1,23 @@
 """The control (the reference in the program's place, its ARIMA bank in
 bfloat16) must come out not correct.  At the rehearsal's tiny size the
 bank makes too few forecasts to tell, so this runs at the cell's own size
-over its first stream window (32,768 requests)."""
+over its first stream window (32,768 requests): on the OOI cell as it is,
+and on the GAGE cell under ``md2``, the paper's mining baseline, whose
+reference forecasts on the same bank."""
 import control
 
 
 def test_bfloat16_control_fails():
     for seed in (1, 2, 3):
         row = control.control("ooi_vdc_128g.paper", seed, windows=1)
+        assert not row["correct"], row
+        assert row["checks"]["ops_differ"]["value"] > 0
+
+
+def test_md2_bfloat16_control_fails():
+    for seed in (1, 2, 3):
+        row = control.control("gage_vdc_32g.paper", seed, windows=1,
+                              strategy="md2")
         assert not row["correct"], row
         assert row["checks"]["ops_differ"]["value"] > 0
 

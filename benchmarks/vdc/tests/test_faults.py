@@ -11,13 +11,16 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def run(fault: str, workload: str, tmp_path_factory, size: str = "tiny",
-        strategy: str | None = None, md2_bank: bool = False) -> dict:
+        strategy: str | None = None, md2_bank: bool = False,
+        md2_planned: bool = False) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=str(
                    tmp_path_factory.getbasetemp() / "jax_cache"))
     extra = [] if strategy is None else ["--strategy", strategy]
     if md2_bank:
         extra.append("--md2-bank")
+    if md2_planned:
+        extra.append("--md2-planned")
     p = subprocess.run(
         [sys.executable, os.path.join(HERE, "fault_run.py"), fault, size,
          *extra, "--workload", workload, "--seed", "3000000123",
@@ -29,26 +32,30 @@ def run(fault: str, workload: str, tmp_path_factory, size: str = "tiny",
 
 def _case(*values):
     """A parametrised case named as pytest names a plain tuple, leaving out
-    a strategy of ``None`` (the cell's own) and an unforced bank."""
+    a strategy of ``None`` (the cell's own), an unforced bank and an
+    unplanned ``md2``."""
     return pytest.param(*values, id="-".join(v for v in values if v))
 
 
 MD2_BANK = "md2-bank"
+MD2_PLANNED = "md2-planned"
 
 
 # "cache_only", the paper's "Cache Only" baseline, run on the OOI cell: the
 # engine serves every window through its static block replay (_run_static).
 # "md2", the paper's mining baseline, run on the GAGE cell with its ARIMA on
-# the fixed-width bank: it predicts online inside the event loop.
-@pytest.mark.parametrize("workload,strategy,bank", [
-    _case("ooi_vdc_128g.paper", None, None),
-    _case("gage_vdc_32g.paper", None, None),
-    _case("ooi_vdc_128g.paper", "cache_only", None),
-    _case("gage_vdc_32g.paper", "md2", MD2_BANK),
+# the fixed-width bank, online and through a window planner.
+@pytest.mark.parametrize("workload,strategy,bank,planned", [
+    _case("ooi_vdc_128g.paper", None, None, None),
+    _case("gage_vdc_32g.paper", None, None, None),
+    _case("ooi_vdc_128g.paper", "cache_only", None, None),
+    _case("gage_vdc_32g.paper", "md2", MD2_BANK, None),
+    _case("gage_vdc_32g.paper", "md2", MD2_BANK, MD2_PLANNED),
 ])
-def test_sound_run_is_correct(workload, strategy, bank, tmp_path_factory):
+def test_sound_run_is_correct(workload, strategy, bank, planned,
+                              tmp_path_factory):
     out = run("none", workload, tmp_path_factory, strategy=strategy,
-              md2_bank=bank == MD2_BANK)
+              md2_bank=bank == MD2_BANK, md2_planned=planned == MD2_PLANNED)
     assert out["correct"], out["checks"]
     assert out["attempted"] > 0 and out["failed"] == 0
     assert out["metrics"] == {}          # a rehearsal prints no device metric
@@ -66,24 +73,26 @@ def test_sound_run_is_correct(workload, strategy, bank, tmp_path_factory):
     _case("static_answer_altered", "ooi_vdc_128g.paper", "tiny", "cache_only"),
     _case("static_counter_altered", "ooi_vdc_128g.paper", "tiny",
           "cache_only"),
+    _case("md2_single_series", "gage_vdc_32g.paper", "tiny", "md2"),
 ])
 def test_fault_is_caught(fault, workload, size, strategy, tmp_path_factory):
     out = run(fault, workload, tmp_path_factory, size, strategy)
     assert not out["correct"], out["checks"]
+    if strategy == "md2":
+        # md2's faults lie in its forecasts, so they show in its ops
+        assert out["checks"]["ops_differ"]["value"] > 0
 
 
-def test_md2_single_series_program_is_caught(tmp_path_factory):
-    """``md2`` as the program has it (``ARIMA(bank=False)``): its forecasts
-    are not the bank's, so its ops depart from the reference's."""
-    out = run("none", "gage_vdc_32g.paper", tmp_path_factory, strategy="md2")
-    assert not out["correct"], out["checks"]
-    assert out["checks"]["ops_differ"]["value"] > 0
-
-
-def test_md2_dropped_op_is_caught(tmp_path_factory):
-    """One op left out of one online ``observe`` call is one request whose
-    ops differ; what it does to later outcomes is not asked."""
+@pytest.mark.parametrize("planned", [
+    pytest.param(None, id="online"),
+    pytest.param(MD2_PLANNED, id="planned"),
+])
+def test_md2_dropped_op_is_caught(planned, tmp_path_factory):
+    """One op left out of one request's ``md2`` ops, where the route in use
+    produces them, is one request whose ops differ; what it does to later
+    outcomes is not asked."""
     out = run("md2_op_dropped", "gage_vdc_32g.paper", tmp_path_factory,
-              strategy="md2", md2_bank=True)
+              strategy="md2", md2_bank=True,
+              md2_planned=planned == MD2_PLANNED)
     assert not out["correct"], out["checks"]
     assert out["checks"]["ops_differ"]["value"] == 1
